@@ -100,6 +100,8 @@ def acc_ratio_cached() -> float:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = []
     print(run(rows))
     for r in rows:

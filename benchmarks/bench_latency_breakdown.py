@@ -95,6 +95,8 @@ def run(csv_rows: list) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = []
     print(run(rows))
     for r in rows:

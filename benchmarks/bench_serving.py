@@ -1623,6 +1623,8 @@ if __name__ == "__main__":
                     help="tracked run-history JSON (default: repo-root "
                          "BENCH_serving.json); every run appends a record")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows: list = []
     out = run(rows, smoke=args.smoke)
     for r in rows:

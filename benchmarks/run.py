@@ -16,6 +16,8 @@ import traceback
 
 def main() -> None:
     rows: list[tuple[str, str, str]] = []
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_accuracy, bench_compression,
                             bench_kernels, bench_latency_breakdown,
                             bench_serving, bench_throughput)

@@ -71,6 +71,10 @@ class PackedLinear:
                    before the matmul); ones when folded into the producer.
       bias:        [N] or None.
       group_size:  static.
+      tp:          static — how a serving mesh splits the layer over its
+                   ``model`` axis: ``"col"`` (N), ``"row"`` (K) or None.
+                   Set by `distributed.sharding.tag_tensor_parallel`;
+                   the fused kernel follows it under `shard_map`.
     """
 
     qweight: jax.Array
@@ -79,6 +83,7 @@ class PackedLinear:
     input_scale: jax.Array
     bias: jax.Array | None
     group_size: int
+    tp: str | None = None
 
     @property
     def k(self) -> int:
@@ -93,11 +98,11 @@ class PackedLinear:
         return ([(ga("qweight"), self.qweight), (ga("scales"), self.scales),
                  (ga("zeros"), self.zeros),
                  (ga("input_scale"), self.input_scale),
-                 (ga("bias"), self.bias)], self.group_size)
+                 (ga("bias"), self.bias)], (self.group_size, self.tp))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, group_size=aux)
+        return cls(*children, group_size=aux[0], tp=aux[1])
 
 
 def pack_linear(q: jax.Array, scales: jax.Array, zeros: jax.Array,

@@ -84,6 +84,41 @@ def _resolve_impl(impl: str) -> str:
     return "kernel" if platform == "tpu" else "ref"
 
 
+def _kernel_matmul(x2: jax.Array, p: PackedLinear, compute_dtype, *,
+                   interpret: bool) -> jax.Array:
+    """The fused Pallas kernel, per shard when a serving mesh splits ``p``.
+
+    The compiler cannot partition a ``pallas_call``, so on a mesh whose
+    ``model`` axis splits the layer (``p.tp``) the kernel runs under
+    `jax.shard_map`: column-parallel layers multiply the whole activation
+    by their N-shard, row-parallel ones their K-slice of it by their
+    K-shard and sum the partial products over ``model``.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import current_mesh
+    from repro.kernels import ops as kops  # lazy: avoid circular import
+
+    def run(x_, q_, s_, z_):
+        local = dataclasses.replace(p, qweight=q_, scales=s_, zeros=z_)
+        return kops.awq_matmul(x_, local, compute_dtype=compute_dtype,
+                               interpret=interpret)
+
+    mesh = current_mesh()
+    if p.tp is None or mesh is None or mesh.shape.get("model", 1) == 1:
+        return run(x2, p.qweight, p.scales, p.zeros)
+    if p.tp == "col":
+        w = P(None, "model")
+        return jax.shard_map(run, mesh=mesh, in_specs=(P(), w, w, w),
+                             out_specs=w, check_vma=False)(
+            x2, p.qweight, p.scales, p.zeros)
+    w = P("model", None)
+    return jax.shard_map(
+        lambda *a: jax.lax.psum(run(*a), "model"), mesh=mesh,
+        in_specs=(P(None, "model"), w, w, w), out_specs=P(),
+        check_vma=False)(x2, p.qweight, p.scales, p.zeros)
+
+
 def qlinear_apply(p: PackedLinear, x: jax.Array,
                   impl: str | None = None,
                   cfg: ExecutionConfig | None = None) -> jax.Array:
@@ -110,9 +145,8 @@ def qlinear_apply(p: PackedLinear, x: jax.Array,
         impl = "ref"  # hybrid threshold: tiny GEMV stays on the generic path
 
     if impl in ("kernel", "kernel_interpret"):
-        from repro.kernels import ops as kops  # lazy: avoid circular import
-        y = kops.awq_matmul(x2, p, compute_dtype=cfg.compute_dtype,
-                            interpret=(impl == "kernel_interpret"))
+        y = _kernel_matmul(x2, p, cfg.compute_dtype,
+                           interpret=(impl == "kernel_interpret"))
     else:
         w = dequantize_packed(p, cfg.compute_dtype)
         y = jnp.dot(x2, w, preferred_element_type=jnp.float32)

@@ -222,6 +222,29 @@ def param_pspec(path: str, leaf: Any, mesh: Mesh, cfg=None) -> P:
     return P(*([None] * len(shape)))  # norms, scalars, conv, A_log, ...
 
 
+def tag_tensor_parallel(params: Any, mesh: Mesh, cfg=None) -> Any:
+    """Record on every `PackedLinear` how `param_pspec` splits it over the
+    ``model`` axis of ``mesh`` (``tp="col"``: N, ``"row"``: K, None:
+    replicated), so `core.qlinear` can run the fused kernel per shard
+    under `shard_map` exactly as the weights are laid out."""
+    import dataclasses
+
+    from repro.core.packing import PackedLinear
+    from repro.utils.tree import path_str
+
+    def tag(path, node):
+        if not isinstance(node, PackedLinear):
+            return node
+        spec = tuple(param_pspec(path_str(path) + "/qweight", node.qweight,
+                                 mesh, cfg))
+        k_ax, n_ax = ([None, None] + list(spec))[-2:]
+        tp = "row" if k_ax == "model" else "col" if n_ax == "model" else None
+        return dataclasses.replace(node, tp=tp)
+
+    return jax.tree_util.tree_map_with_path(
+        tag, params, is_leaf=lambda x: isinstance(x, PackedLinear))
+
+
 def zero1_pspec(pspec: P, shape: tuple[int, ...], mesh: Mesh) -> P:
     """ZeRO-1: additionally shard optimizer moments over the data axis.
 
@@ -302,9 +325,9 @@ def paged_cache_pspec(path: str, leaf: Any, mesh: Mesh, cfg=None) -> P:
     pager's free list / refcounts / page tables stay device-agnostic and
     a physical page is simply striped across the mesh:
 
-      k/v pools   [L, N, P, Hkv, hd] → heads over ``model``
-      ks/vs strips[L, N, P, Hkv]     → heads over ``model``
-      ring k/v    [L, B, W, Hkv, hd] → heads over ``model`` (same rule)
+      k/v pools   [L, N, Hkv, P, hd] → heads over ``model``
+      ks/vs strips[L, N, Hkv, P]     → heads over ``model``
+      ring k/v    [L, B, W, Hkv, hd] → heads over ``model``
 
     Bounded per-slot state (SSM states, MLA latents, conv tails) is
     replicated — its footprint is small by construction. Head counts that
@@ -315,11 +338,16 @@ def paged_cache_pspec(path: str, leaf: Any, mesh: Mesh, cfg=None) -> P:
     shape = tuple(leaf.shape)
     leafname = path.split("/")[-1]
     msize = mesh.shape.get("model", 1)
-    if leafname in ("k", "v") and len(shape) >= 2 and shape[-2] % msize == 0:
-        return P(*([None] * (len(shape) - 2) + ["model", None]))
-    if leafname in ("ks", "vs") and shape and shape[-1] % msize == 0:
-        return P(*([None] * (len(shape) - 1) + ["model"]))
-    return P(*([None] * len(shape)))
+    # distance of the head axis from the end of the leaf's shape
+    if "kv_pool" in path.split("/"):
+        head = {"k": 3, "v": 3, "ks": 2, "vs": 2}.get(leafname)
+    else:
+        head = {"k": 2, "v": 2}.get(leafname)
+    if head is None or len(shape) < head or shape[-head] % msize:
+        return P(*([None] * len(shape)))
+    spec = [None] * len(shape)
+    spec[-head] = "model"
+    return P(*spec)
 
 
 def spill_sharding(mesh: Mesh) -> NamedSharding:
@@ -359,17 +387,19 @@ def handoff_sharding(mesh: Mesh) -> NamedSharding:
     return spill_sharding(mesh)
 
 
-def serving_mesh(model: int | None = None) -> Mesh:
-    """A 1-D ``('model',)`` mesh over the first ``model`` local devices.
+def serving_mesh(model: int | None = None, devices=None) -> Mesh:
+    """A 1-D ``('model',)`` mesh over the first ``model`` of ``devices``.
 
     The serving engine's tensor-parallel axis: weights column/row-shard
     through `param_pspec`, page pools shard over KV heads through
     `paged_cache_pspec`, and everything host-visible (page tables, token
-    blocks, sampled tokens) stays replicated. ``model=None`` takes every
-    local device; ``model=1`` is the degenerate mesh whose dispatches are
-    identical to the unsharded path.
+    blocks, sampled tokens) stays replicated. ``devices`` defaults to
+    every local device; a fleet hands each replica its own disjoint slice
+    (`launch.specs.FleetSpec.build`). ``model=None`` takes all of
+    ``devices``; ``model=1`` is the degenerate mesh whose dispatches are
+    identical to the unsharded path, placed on ``devices[0]``.
     """
-    devices = jax.devices()
+    devices = list(jax.devices() if devices is None else devices)
     n = len(devices) if model is None else model
     if n < 1 or n > len(devices):
         raise ValueError(f"serving_mesh(model={model}): have "
@@ -392,21 +422,3 @@ def make_sharding(tree: Any, mesh: Mesh, rule, cfg=None) -> Any:
                         pspec_tree(tree, mesh, rule, cfg),
                         is_leaf=lambda x: isinstance(x, P))
 
-
-# ---------------------------------------------------------------------------
-# shard_map compat
-# ---------------------------------------------------------------------------
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """`jax.shard_map` on new jax, `jax.experimental.shard_map` on old.
-
-    The replication-check kwarg was renamed `check_rep` → `check_vma` across
-    the move; callers use the new name and we translate when falling back.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)

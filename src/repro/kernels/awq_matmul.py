@@ -33,18 +33,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import PACK
-from repro.utils.compat import CompilerParams as _CompilerParams
 
 
 def _unpack_dequant(qw_block, s_block, z_block, block_k: int, block_n: int,
                     group_size: int, compute_dtype):
     """[bk//8, bn] int32 → [bk, bn] float, dequantized (in-VMEM pipeline)."""
-    w32 = qw_block.astype(jnp.uint32)  # [bk//8, bn]
-    # Shift+mask unpack, mirroring the paper's unpack unit (Fig. 4b). The
-    # stack axis is the nibble index j ⇒ original row = word_row * 8 + j.
-    nibs = [((w32 >> jnp.uint32(4 * j)) & jnp.uint32(0xF))
+    # Shift+mask unpack, mirroring the paper's unpack unit (Fig. 4b), in
+    # int32: Mosaic has no uint32→float cast, and a logical shift + 0xF
+    # mask leaves 0..15, which int32→float converts exactly. The stack
+    # axis is the nibble index j ⇒ original row = word_row * 8 + j.
+    nibs = [jax.lax.shift_right_logical(qw_block, jnp.int32(4 * j)) & 0xF
             for j in range(PACK)]
-    q = jnp.stack(nibs, axis=1).reshape(block_k, block_n)  # uint32
+    q = jnp.stack(nibs, axis=1).reshape(block_k, block_n)  # int32
     groups = block_k // group_size
     qf = q.reshape(groups, group_size, block_n).astype(jnp.float32)
     z = z_block.astype(jnp.float32)[:, None, :]   # [g, 1, bn]
@@ -112,7 +112,8 @@ def awq_matmul_pallas(x: jax.Array, qweight: jax.Array, scales: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        name="awq_matmul",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, qweight, scales, zeros)
 
@@ -178,6 +179,7 @@ def awq_gateup_pallas(x, qw_gate, s_gate, z_gate, qw_up, s_up, z_up, *,
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32),
                         pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        name="awq_gateup",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, qw_gate, s_gate, z_gate, qw_up, s_up, z_up)

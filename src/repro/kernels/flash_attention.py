@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.utils.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -122,7 +121,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         pltpu.VMEM((block_q, 128), jnp.float32),
                         pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)
     return out.reshape(b, h, s, hd)

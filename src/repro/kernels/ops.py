@@ -3,9 +3,14 @@ padding, platform dispatch).
 
 The wrapper implements the two block regimes of DESIGN.md §2: a GEMV-like
 schedule for decode (tiny M) and a GEMM schedule for prefill/training-shape
-matmuls. VMEM budgeting note: one grid step holds
-``bm*bk (x) + bk/8*bn*4 (qw) + 2*bk/GS*bn (meta) + bm*bn*4 (acc)`` bytes;
-the defaults keep this well under 8 MB for every supported shape.
+matmuls. VMEM budgeting note: one grid step holds the double-buffered
+blocks ``2 * (bm*bk*2 (x, bf16) + bk/8*bn*4 (qw) + bk/GS*bn*5 (f32
+scales + int8 zeros) + bm*bn*4 (out))`` plus ``bm*bn*4`` (acc) plus the
+unpack temporaries ``bk*bn*10`` (int32 codes, f32 and bf16 weights).
+The largest Qwen2.5-0.5B block — the down projection, where K/GS = 76
+forces bk = K = 4864, at bm = 256, bn = 128 — is ≈ 6 MB of blocks plus
+≈ 6 MB of temporaries, inside the 16 MiB of scoped VMEM a v5e kernel
+gets by default (tests/test_tpu_compile.py compiles it for that chip).
 """
 from __future__ import annotations
 
@@ -32,7 +37,11 @@ def choose_blocks(m: int, k: int, n: int, group_size: int,
     """(block_m, block_n, block_k) for the fused kernel.
 
     * block_k must be a multiple of the dequant group (metadata travels with
-      its weights — the AWQ_MACRO invariant) and divide K.
+      its weights — the AWQ_MACRO invariant) and divide K. The scale and
+      zero blocks are ``[block_k / GS, bn]``, and Mosaic takes a block's
+      second-minor dim only as a multiple of 8 or the array's whole dim,
+      so block_k / GS is the largest multiple of 8 dividing K / GS (up to
+      1024 rows of K), else the whole K.
     * block_n multiples of 128 keep the MXU lane dimension full.
     * block_m picks the schedule: ≤ 8 rows ride one 8-sublane block (the
       decode GEMV regime — every weight block is streamed exactly once);
@@ -43,7 +52,10 @@ def choose_blocks(m: int, k: int, n: int, group_size: int,
       — those pad up to the next 8-sublane boundary and take it as one
       block when ≤ 256 (single grid row) instead of degrading to bm=8.
     """
-    block_k = _divisor_block(k, group_size, 1024)
+    groups = k // group_size
+    per_block = [g for g in range(8, 1024 // group_size + 1, 8)
+                 if groups % g == 0]
+    block_k = per_block[-1] * group_size if per_block else k
     block_n = _divisor_block(n, 128, 512) if n % 128 == 0 else \
         _divisor_block(n, 8, 512)
     if m <= 8:

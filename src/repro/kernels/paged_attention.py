@@ -23,8 +23,8 @@ Two entry points over one kernel body:
   * `paged_attention` — the single-token decode form (``C = 1``), kept as
     the stable API for the decode hot path and the kernel test suite.
 
-Layout: q ``[B, C, Hkv, G, hd]`` (head = kv_head·G + group), pools
-``[N, P, Hkv, hd]`` int8 with scales ``[N, P, Hkv]`` f32, page_table
+Layout: q ``[B, C, Hkv, G, hd]`` (head = kv_head·G + group), head-major
+pools ``[N, Hkv, P, hd]`` int8 with scales ``[N, Hkv, P]`` f32, page_table
 ``[B, pages_per_slot]`` int32, pos ``[B, C]`` int32 (inclusive last valid
 absolute position per query; ``-1`` = padding query, fully masked). Grid
 ``(B, Hkv, pages_per_slot)``, pages innermost (accumulation axis); the
@@ -44,8 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -60,6 +58,7 @@ def _paged_attn_kernel(tables_ref, pos_ref, rpos_ref,  # scalar prefetch
                        page_size: int, n_blocks: int, n_chunk: int,
                        n_groups: int, scale: float, window: int):
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -69,14 +68,25 @@ def _paged_attn_kernel(tables_ref, pos_ref, rpos_ref,  # scalar prefetch
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                    # [gp, hd]
-    # fused dequant: int8 codes × per-(position, head) scale strip, VMEM-only
-    k = k_ref[0][:, 0].astype(jnp.float32) \
-        * ks_ref[0][:, :1].astype(jnp.float32)             # [P, hd]
-    v = v_ref[0][:, 0].astype(jnp.float32) \
-        * vs_ref[0][:, :1].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                    # [P, hd] codes
+    v = v_ref[0, 0].astype(jnp.float32)
+    # fused dequant, VMEM-only: the per-(head, position) scale is constant
+    # along hd, so it scales score columns (K) and probability columns (V)
+    # as a [1, P] row instead of rescaling the [P, hd] code tile. The
+    # scale block holds every head's strip ([Hkv, P], a legal tile);
+    # this cell's row is picked with a mask-and-sum over the head axis.
+    ks_all = ks_ref[0].astype(jnp.float32)                 # [Hkv, P]
+    vs_all = vs_ref[0].astype(jnp.float32)
+    if ks_all.shape[0] == 1:
+        ks_row, vs_row = ks_all, vs_all
+    else:
+        mine = jax.lax.broadcasted_iota(jnp.int32, ks_all.shape, 0) == h
+        ks_row = jnp.sum(jnp.where(mine, ks_all, 0.0), axis=0, keepdims=True)
+        vs_row = jnp.sum(jnp.where(mine, vs_all, 0.0), axis=0, keepdims=True)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32) \
+        * (ks_row * scale)
     # per-row query position: row r of the q block is chunk token r // G.
     # pos lives in SMEM (scalar prefetch); a vector gather out of SMEM is
     # not expressible, so select it with a static unroll over the (small,
@@ -123,7 +133,8 @@ def _paged_attn_kernel(tables_ref, pos_ref, rpos_ref,  # scalar prefetch
     l_new = l_prev * alpha + jnp.broadcast_to(
         jnp.sum(p, axis=1)[:, None], l_prev.shape)
     acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p * vs_row, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     m_ref[...] = m_new
     l_ref[...] = l_new
 
@@ -161,8 +172,8 @@ def paged_attention_chunk(q: jax.Array, k_pool: jax.Array, ks: jax.Array,
     """Fused dequant + multi-query masked attention over int8 KV pages.
 
     q ``[B, C, Hkv, G, hd]`` — C queries per row (prefill chunk, token
-    tree, or decode at C = 1); k/v pools ``[N, P, Hkv, hd]`` int8; ks/vs
-    ``[N, P, Hkv]`` f32; page_table ``[B, pages_per_slot]`` int32 (one
+    tree, or decode at C = 1); k/v pools ``[N, Hkv, P, hd]`` int8; ks/vs
+    ``[N, Hkv, P]`` f32; page_table ``[B, pages_per_slot]`` int32 (one
     row per batch row — all C queries of a row read the same slot's
     pages); pos ``[B, C]`` int32 per-query inclusive **slot** positions
     (``-1`` ⇒ padding query, output 0): in-span tokens always occupy
@@ -181,7 +192,7 @@ def paged_attention_chunk(q: jax.Array, k_pool: jax.Array, ks: jax.Array,
     outside the span, so they never leak into the softmax.
     """
     b, c, hkv, g, hd = q.shape
-    page_size = k_pool.shape[1]
+    page_size = k_pool.shape[2]
     n_blocks = page_table.shape[1]
     scale = scale if scale is not None else hd ** -0.5
     if rpos is None:
@@ -204,6 +215,17 @@ def paged_attention_chunk(q: jax.Array, k_pool: jax.Array, ks: jax.Array,
             [am, jnp.zeros((b, gp - rows, c), am.dtype)], axis=1)
 
     grid = (b, hkv, n_blocks)
+    # one head's [P, hd] code tile of the page per grid cell (the last two
+    # block dims are the array's own, as Mosaic requires); the scale block
+    # takes the page's whole [Hkv, P] strip for the same reason
+    kv_spec = pl.BlockSpec(
+        (1, 1, page_size, hd),
+        lambda bi, hi, ji, tables, pos_, rpos_, _nb=n_blocks:
+        (tables[bi * _nb + ji], hi, 0, 0))
+    scale_spec = pl.BlockSpec(
+        (1, hkv, page_size),
+        lambda bi, hi, ji, tables, pos_, rpos_, _nb=n_blocks:
+        (tables[bi * _nb + ji], 0, 0))
     kernel = functools.partial(_paged_attn_kernel, page_size=page_size,
                                n_blocks=n_blocks, n_chunk=c, n_groups=g,
                                scale=scale, window=window)
@@ -214,18 +236,7 @@ def paged_attention_chunk(q: jax.Array, k_pool: jax.Array, ks: jax.Array,
             pl.BlockSpec((1, 1, gp, hd),
                          lambda bi, hi, ji, tables, pos_, rpos_:
                          (bi, hi, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda bi, hi, ji, tables, pos_, rpos_,
-                         _nb=n_blocks: (tables[bi * _nb + ji], 0, hi, 0)),
-            pl.BlockSpec((1, page_size, 1),
-                         lambda bi, hi, ji, tables, pos_, rpos_,
-                         _nb=n_blocks: (tables[bi * _nb + ji], 0, hi)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda bi, hi, ji, tables, pos_, rpos_,
-                         _nb=n_blocks: (tables[bi * _nb + ji], 0, hi, 0)),
-            pl.BlockSpec((1, page_size, 1),
-                         lambda bi, hi, ji, tables, pos_, rpos_,
-                         _nb=n_blocks: (tables[bi * _nb + ji], 0, hi)),
+            kv_spec, scale_spec, kv_spec, scale_spec,
             pl.BlockSpec((1, gp, c),
                          lambda bi, hi, ji, tables, pos_, rpos_:
                          (bi, 0, 0)),
@@ -242,7 +253,8 @@ def paged_attention_chunk(q: jax.Array, k_pool: jax.Array, ks: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, hd), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        name="paged_attention_chunk",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(page_table.reshape(-1).astype(jnp.int32),
       pos.reshape(-1).astype(jnp.int32),
@@ -276,8 +288,6 @@ def paged_attention_chunk_sharded(q: jax.Array, k_pool: jax.Array,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map
-
     if mesh.shape.get("model", 1) == 1:
         return paged_attention_chunk(q, k_pool, ks, v_pool, vs, page_table,
                                      pos, scale=scale, rpos=rpos,
@@ -287,8 +297,8 @@ def paged_attention_chunk_sharded(q: jax.Array, k_pool: jax.Array,
         rpos = pos
     if amask is None:
         amask = default_amask(pos, window)
-    head = P(None, None, "model")                       # [N, P, Hkv]
-    return shard_map(
+    head = P(None, "model", None)                       # [N, Hkv, P]
+    return jax.shard_map(
         lambda q_, k_, ks_, v_, vs_, t_, p_, rp_, am_: paged_attention_chunk(
             q_, k_, ks_, v_, vs_, t_, p_, scale=scale, rpos=rp_, amask=am_,
             window=window, interpret=interpret),

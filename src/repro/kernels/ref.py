@@ -49,26 +49,30 @@ def awq_gateup_ref(x: jax.Array, qw_gate, s_gate, z_gate, qw_up, s_up, z_up,
     return jax.nn.silu(g) * u
 
 
+def gather_pages(leaf, page_table):
+    """Head-major pool leaf ``[N, Hkv, P, ...]`` → the slots' logical view
+    ``[B, pages_per_slot · P, Hkv, ...]`` through ``page_table [B, n]``."""
+    g = jnp.swapaxes(leaf[page_table], 2, 3)        # [B, n, P, Hkv, ...]
+    return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
+
+
 def paged_attention_ref(q, k_pool, ks, v_pool, vs, page_table, pos, *,
                         scale=None):
     """Oracle for the fused dequant + paged-attention decode kernel.
 
-    q [B, Hkv, G, hd]; k/v pools [N, P, Hkv, hd] int8; ks/vs [N, P, Hkv]
+    q [B, Hkv, G, hd]; k/v pools [N, Hkv, P, hd] int8; ks/vs [N, Hkv, P]
     f32 scale strips; page_table [B, pages_per_slot] int32; pos [B] int32
     (inclusive last valid position). Gathers the slot's pages into logical
     order, dequantizes, then runs plain masked softmax attention —
     exactly the jnp fallback path in `models.attention`.
     """
     b, hkv, g, hd = q.shape
-    page_size = k_pool.shape[1]
-    s_slot = page_table.shape[1] * page_size
+    s_slot = page_table.shape[1] * k_pool.shape[2]
     scale = scale if scale is not None else hd ** -0.5
-    k = (k_pool.astype(jnp.float32)
-         * ks[..., None].astype(jnp.float32))[page_table]
-    v = (v_pool.astype(jnp.float32)
-         * vs[..., None].astype(jnp.float32))[page_table]
-    k = k.reshape(b, s_slot, hkv, hd)
-    v = v.reshape(b, s_slot, hkv, hd)
+    k = gather_pages(k_pool.astype(jnp.float32)
+                     * ks[..., None].astype(jnp.float32), page_table)
+    v = gather_pages(v_pool.astype(jnp.float32)
+                     * vs[..., None].astype(jnp.float32), page_table)
     sc = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32), k) * scale
     valid = jnp.arange(s_slot)[None, :] <= pos[:, None]    # [B, S]
     sc = jnp.where(valid[:, None, None, :], sc, -1e30)
@@ -128,7 +132,7 @@ def paged_attention_chunk_ref(q, k_pool, ks, v_pool, vs, page_table, pos, *,
 
     q [B, C, Hkv, G, hd] — C queries per batch row (a prefill chunk, a
     speculation tree, or a single decode token at C=1); k/v pools
-    [N, P, Hkv, hd] int8 with ks/vs [N, P, Hkv] f32 scale strips;
+    [N, Hkv, P, hd] int8 with ks/vs [N, Hkv, P] f32 scale strips;
     page_table [B, pages_per_slot] int32 (one table row per batch row —
     all C queries of a row belong to the same request slot); pos [B, C]
     int32 absolute query **slot** positions, ``-1`` marking padding
@@ -142,15 +146,12 @@ def paged_attention_chunk_ref(q, k_pool, ks, v_pool, vs, page_table, pos, *,
     ``l == 0`` flush.
     """
     b, c, hkv, g, hd = q.shape
-    page_size = k_pool.shape[1]
-    s_slot = page_table.shape[1] * page_size
+    s_slot = page_table.shape[1] * k_pool.shape[2]
     scale = scale if scale is not None else hd ** -0.5
-    k = (k_pool.astype(jnp.float32)
-         * ks[..., None].astype(jnp.float32))[page_table]
-    v = (v_pool.astype(jnp.float32)
-         * vs[..., None].astype(jnp.float32))[page_table]
-    k = k.reshape(b, s_slot, hkv, hd)
-    v = v.reshape(b, s_slot, hkv, hd)
+    k = gather_pages(k_pool.astype(jnp.float32)
+                     * ks[..., None].astype(jnp.float32), page_table)
+    v = gather_pages(v_pool.astype(jnp.float32)
+                     * vs[..., None].astype(jnp.float32), page_table)
     sc = jnp.einsum("bckgd,bskd->bckgs", q.astype(jnp.float32), k) * scale
     vis = chunk_visibility_ref(pos, s_slot=s_slot, rpos=rpos, amask=amask,
                                window=window)              # [B, C, S]

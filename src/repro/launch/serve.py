@@ -3,7 +3,10 @@
 The end-to-end path of the paper (§III-A "fully automated"): init (or load)
 float params → calibration forward → AWQ search + pack (GS=64 INT4) → serve
 with the fused dequant-matmul path. ``--quant none`` serves the float
-baseline (the paper's 2.8 tok/s side of Table III).
+baseline (the paper's 2.8 tok/s side of Table III). Requests go through
+the continuous-batching engine (`submit`/`drain`) over int8 paged KV —
+on a TPU that is the path where both Pallas kernels (AWQ matmul and
+paged attention) run, the one ``chip_smoke.py`` checks.
 
 With ``--replicas N`` (or any fleet flag) the launcher serves a
 continuous-batching **fleet** instead: N `GenerationEngine` replicas —
@@ -35,6 +38,19 @@ from repro.data import make_dataset
 from repro.launch.specs import FleetSpec, ReplicaSpec
 from repro.models import build_model
 from repro.serving import GenerationEngine, SamplerConfig
+from repro.utils.compile_cache import enable_compile_cache
+
+
+def awq_quantize(model, params, group_size: int = 64):
+    """Calibrate on a synthetic batch and AWQ-pack every quantizable
+    linear → (params, `PTQReport`)."""
+    cfg = model.cfg
+    ds = make_dataset(cfg, 2, min(64, cfg.max_seq_len), seed=123)
+    calib = {k: jnp.asarray(v) for k, v in ds.batch_at(0).items()}
+    with CalibrationCapture() as cap:
+        model.loss(params, calib)
+    qcfg = AWQConfig(quant=QuantConfig(group_size=group_size))
+    return quantize_params(params, cap.stats, qcfg)
 
 
 def main(argv=None) -> dict:
@@ -51,7 +67,7 @@ def main(argv=None) -> dict:
     # fleet flags (k8s-style: scale + pod template + drain budget)
     ap.add_argument("--replicas", type=int, default=0,
                     help="serve a Router fleet of N replicas instead of "
-                         "one static-batch engine (0 = classic path)")
+                         "one engine (0 = one engine)")
     ap.add_argument("--mesh-axis", type=int, default=1,
                     help="per-replica tensor-parallel 'model' axis width")
     ap.add_argument("--disagg", action="store_true",
@@ -60,6 +76,7 @@ def main(argv=None) -> dict:
                     help="drain_replica step budget (seconds) for elastic "
                          "scale-down")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
@@ -69,13 +86,8 @@ def main(argv=None) -> dict:
           f"{model_size_bytes(params, quantized=False)/1e6:.2f} MB")
 
     if args.quant == "awq":
-        ds = make_dataset(cfg, 2, min(64, cfg.max_seq_len), seed=123)
-        calib = {k: jnp.asarray(v) for k, v in ds.batch_at(0).items()}
         t0 = time.time()
-        with CalibrationCapture() as cap:
-            model.loss(params, calib)
-        qcfg = AWQConfig(quant=QuantConfig(group_size=args.group_size))
-        params, report = quantize_params(params, cap.stats, qcfg)
+        params, report = awq_quantize(model, params, args.group_size)
         print(f"[serve] AWQ PTQ in {time.time()-t0:.1f}s: "
               f"{len(report.quantized)} linears quantized "
               f"({len(report.calibrated)} calibrated), "
@@ -85,21 +97,38 @@ def main(argv=None) -> dict:
 
     if args.replicas > 0:
         return serve_fleet(model, params, args)
+    return serve_batch(model, params, args)
 
+
+def serve_batch(model, params, args) -> dict:
+    """One continuous-batching engine: ``--batch`` prompts submitted at
+    once and drained, after a warm-up that compiles the step family."""
+    page = 16
+    max_seq = args.prompt_len + args.max_new
+    max_seq += -max_seq % page
     engine = GenerationEngine(
-        model, params, max_seq=args.prompt_len + args.max_new,
+        model, params, max_seq=max_seq, num_slots=args.batch,
+        page_size=page, prefill_chunk=page, kv_quant="int8",
         sampler=SamplerConfig(temperature=args.temperature))
-    ds = make_dataset(cfg, args.batch, args.prompt_len, seed=args.seed)
-    prompt = {"tokens": jnp.asarray(ds.batch_at(0)["tokens"])}
+    ds = make_dataset(model.cfg, args.batch, args.prompt_len, seed=args.seed)
+    prompts = np.asarray(ds.batch_at(0)["tokens"])
 
     t0 = time.time()
-    out = engine.generate(prompt, args.max_new)
+    variants = engine.warmup(sampled=args.temperature > 0)
+    compile_s = time.time() - t0
+    t0 = time.time()
+    rids = [engine.submit(p, args.max_new) for p in prompts]
+    out = engine.drain()
     dt = time.time() - t0
-    tput = out.size / dt
-    print(f"[serve] generated {out.shape} tokens in {dt:.2f}s "
-          f"({tput:.1f} tok/s wall on {jax.default_backend()})")
-    print(f"[serve] sample: {out[0][:16].tolist()}")
-    return {"tokens_per_s": tput, "shape": list(out.shape)}
+    useful = sum(len(out[r]) for r in rids)
+    tput = useful / dt
+    print(f"[serve] warm-up compiled {variants} step variants in "
+          f"{compile_s:.1f}s")
+    print(f"[serve] served {len(rids)} requests / {useful} tokens in "
+          f"{dt:.2f}s ({tput:.1f} tok/s wall on {jax.default_backend()})")
+    print(f"[serve] sample: {out[rids[0]][:16].tolist()}")
+    return {"tokens_per_s": tput, "requests": len(rids),
+            "compile_s": compile_s}
 
 
 def serve_fleet(model, params, args) -> dict:
@@ -119,7 +148,8 @@ def serve_fleet(model, params, args) -> dict:
             prefill_mesh_axis=args.mesh_axis,
             decode_mesh_axis=args.mesh_axis,
             engine_kwargs=dict(max_seq=max_seq, num_slots=args.batch,
-                               page_size=page, prefill_chunk=page)),
+                               page_size=page, prefill_chunk=page,
+                               kv_quant="int8")),
         drain_timeout_s=args.drain_timeout)
     print(f"[serve] fleet: {spec.replicas} replica(s), mesh_axis="
           f"{args.mesh_axis}, disagg={args.disagg}, "

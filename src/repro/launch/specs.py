@@ -117,11 +117,13 @@ class ReplicaSpec:
     """One serving replica, declaratively.
 
     ``mesh_axis`` is the replica's tensor-parallel ``model``-axis width
-    (1 = unsharded; the device pool must hold ``mesh_axis`` devices).
-    ``disagg=True`` serves the replica as a `DisaggController`
-    prefill/decode pair with per-side mesh widths instead of one
-    `GenerationEngine`. ``engine_kwargs`` forward verbatim to the engine
-    constructor(s) — shape, KV quant, speculation, preemption knobs.
+    (1 = unsharded). ``disagg=True`` serves the replica as a
+    `DisaggController` prefill/decode pair with per-side mesh widths
+    instead of one `GenerationEngine`; the prefill side takes the first
+    ``prefill_mesh_axis`` of the replica's devices and the decode side
+    the next ``decode_mesh_axis``, so the two never overlap.
+    ``engine_kwargs`` forward verbatim to the engine constructor(s) —
+    shape, KV quant, speculation, preemption knobs.
     """
     mesh_axis: int = 1
     disagg: bool = False
@@ -129,21 +131,41 @@ class ReplicaSpec:
     decode_mesh_axis: int = 1
     engine_kwargs: dict = dataclasses.field(default_factory=dict)
 
-    def build(self, model, params, **overrides):
-        """Construct the replica this spec describes."""
+    @property
+    def devices_needed(self) -> int:
+        """Devices one replica occupies."""
+        if self.disagg:
+            return self.prefill_mesh_axis + self.decode_mesh_axis
+        return self.mesh_axis
+
+    def build(self, model, params, devices=None, **overrides):
+        """Construct the replica this spec describes on ``devices`` (its
+        own slice of the host; ``None`` = the default device, with
+        ``jax.devices()`` prefixes for meshes wider than one)."""
         from repro.distributed import serving_mesh
         from repro.serving import DisaggController, GenerationEngine
         kw = {**self.engine_kwargs, **overrides}
+
+        def mesh(width, offset):
+            if devices is None:
+                return serving_mesh(width) if width > 1 else None
+            devs = devices[offset:offset + width]
+            # one device that is the default one needs no mesh: the
+            # replica keeps the unsharded engine, which also serves archs
+            # a mesh cannot (one-shot prefill, SSM/ring state)
+            if width == 1 and devs[0] == jax.devices()[0]:
+                return None
+            return serving_mesh(width, devs)
+
         if self.disagg:
             return DisaggController(
                 model, params,
-                prefill_mesh=(serving_mesh(self.prefill_mesh_axis)
-                              if self.prefill_mesh_axis > 1 else None),
-                decode_mesh=(serving_mesh(self.decode_mesh_axis)
-                             if self.decode_mesh_axis > 1 else None),
+                prefill_mesh=mesh(self.prefill_mesh_axis, 0),
+                decode_mesh=mesh(self.decode_mesh_axis,
+                                 self.prefill_mesh_axis),
                 **kw)
-        mesh = serving_mesh(self.mesh_axis) if self.mesh_axis > 1 else None
-        return GenerationEngine(model, params, mesh=mesh, **kw)
+        return GenerationEngine(model, params, mesh=mesh(self.mesh_axis, 0),
+                                **kw)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -168,12 +190,30 @@ class FleetSpec:
 
     def build(self, model, params, **overrides):
         """Materialize the fleet: build every replica, wrap the router,
-        optionally precompile each replica's dispatch family."""
+        optionally precompile each replica's dispatch family.
+
+        Replica ``i`` takes devices ``[i·n, (i+1)·n)`` of ``jax.devices()``
+        (``n`` = `ReplicaSpec.devices_needed`), so replicas never share a
+        chip. A host with fewer devices than one-device replicas (a CPU
+        host) keeps them all on the default device; wider replicas that
+        do not fit are refused.
+        """
         from repro.serving import Router
         if self.replicas < 1:
             raise ValueError(f"replicas must be >= 1, got {self.replicas}")
-        fleet = [self.replica.build(model, params, **overrides)
-                 for _ in range(self.replicas)]
+        devs = jax.devices()
+        need = self.replica.devices_needed
+        if self.replicas * need <= len(devs):
+            slices = [devs[i * need:(i + 1) * need]
+                      for i in range(self.replicas)]
+        elif need == 1:
+            slices = [None] * self.replicas
+        else:
+            raise ValueError(
+                f"{self.replicas} replicas x {need} devices do not fit on "
+                f"{len(devs)} devices")
+        fleet = [self.replica.build(model, params, devices=d, **overrides)
+                 for d in slices]
         router = Router(fleet, placement=self.placement,
                         affinity_threshold=self.affinity_threshold)
         if self.warmup:

@@ -33,6 +33,10 @@ from repro.training import AdamWConfig, TrainConfig, make_train_step
 from repro.training.train_step import init_train_state
 
 
+class SimulatedNodeFailure(RuntimeError):
+    """The failure ``--simulate-failure-at`` injects into one step."""
+
+
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen25-05b")
@@ -83,10 +87,12 @@ def main(argv=None) -> dict:
             try:
                 if i == args.simulate_failure_at and not failed_once:
                     failed_once = True
-                    raise RuntimeError("simulated node failure")
+                    raise SimulatedNodeFailure("simulated node failure")
                 state, metrics = step_fn(state, batch)
                 jax.block_until_ready(metrics["loss"])
-            except Exception as e:  # node-failure path: reload + retry
+            except SimulatedNodeFailure as e:  # reload + retry; any other
+                # error (a compile error, a bad shape) is not a node
+                # failure and would recur on every retry, so it propagates
                 print(f"[train] step {i} failed ({e}); recovering from "
                       "latest checkpoint")
                 if ckpt is None or latest_step(args.ckpt_dir) is None:

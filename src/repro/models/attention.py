@@ -250,7 +250,10 @@ def fill_cache_from_prefill(cache, k, v, positions, window: int):
 
 def init_paged_kv_cache(cfg, num_pages: int, page_size: int,
                         dtype=jnp.bfloat16, kv_quant: str | None = None):
-    """Page pool for one layer: ``[num_pages, page_size, Hkv, hd]``.
+    """Page pool for one layer: ``[num_pages, Hkv, page_size, hd]``.
+
+    Head-major, so one (page, head) is a contiguous ``[P, hd]`` tile — the
+    block the paged kernel reads per grid cell.
 
     Physical pages are normally owned by one request slot; prefix sharing
     lets several slots alias read-only pages (the pager refcounts them).
@@ -261,13 +264,13 @@ def init_paged_kv_cache(cfg, num_pages: int, page_size: int,
 
     ``kv_quant`` overrides ``cfg.kv_quant`` for the pool only (the serving
     engine uses this to hold int8 pages under a float model config —
-    quantize-on-commit / dequant-on-gather): int8 codes plus per-(position,
-    head) float32 absmax scale strips ``ks``/``vs``.
+    quantize-on-commit / dequant-on-gather): int8 codes plus per-(head,
+    position) float32 absmax scale strips ``ks``/``vs`` ``[N, Hkv, P]``.
     """
     kv_quant = cfg.kv_quant if kv_quant is None else kv_quant
-    shape = (num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = (num_pages, cfg.num_kv_heads, page_size, cfg.head_dim)
     if kv_quant == "int8":
-        sshape = (num_pages, page_size, cfg.num_kv_heads)
+        sshape = (num_pages, cfg.num_kv_heads, page_size)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "ks": jnp.zeros(sshape, jnp.float32),
@@ -299,7 +302,7 @@ def attention_chunk_paged(p, pool, page_table, x, cfg, *, pos, rpos=None,
     lets windowed layers share the paged pools with global layers).
 
     Execution order is scatter-then-gather: every valid token's K/V is
-    written into ``pool[table[b, pos // P], pos % P]`` first (padding
+    written into ``pool[table[b, pos // P], :, pos % P]`` first (padding
     redirected to the reserved scratch page 0), then each token attends
     over its slot's pages under the three-part visibility rule of
     `kernels.ref.chunk_visibility_ref`: committed pages pass the causal
@@ -319,7 +322,7 @@ def attention_chunk_paged(p, pool, page_table, x, cfg, *, pos, rpos=None,
     as the kernel's reference semantics.
     """
     b, c, _ = x.shape
-    page_size = pool["k"].shape[1]
+    page_size = pool["k"].shape[2]
     valid = pos >= 0
     logical = pos if rpos is None else rpos
     rope_pos = jnp.where(valid, logical, 0)
@@ -344,23 +347,23 @@ def attention_chunk_paged(p, pool, page_table, x, cfg, *, pos, rpos=None,
     if quant:
         k1, ks1 = _kv_quantize(k1)
         v1, vs1 = _kv_quantize(v1)
-        new_pool["ks"] = pool["ks"].at[fp, fo].set(
+        new_pool["ks"] = pool["ks"].at[fp, :, fo].set(
             ks1.reshape(b * c, cfg.num_kv_heads))
-        new_pool["vs"] = pool["vs"].at[fp, fo].set(
+        new_pool["vs"] = pool["vs"].at[fp, :, fo].set(
             vs1.reshape(b * c, cfg.num_kv_heads))
     kv_shape = (b * c, cfg.num_kv_heads, cfg.head_dim)
-    new_pool["k"] = pool["k"].at[fp, fo].set(
+    new_pool["k"] = pool["k"].at[fp, :, fo].set(
         k1.reshape(kv_shape).astype(pool["k"].dtype))
-    new_pool["v"] = pool["v"].at[fp, fo].set(
+    new_pool["v"] = pool["v"].at[fp, :, fo].set(
         v1.reshape(kv_shape).astype(pool["v"].dtype))
     # keep the pool mesh-sharded through the scatter (pools stripe over KV
     # heads on the `model` axis — `distributed.paged_cache_pspec`); without
     # the constraint GSPMD may gather the whole pool onto every device
-    new_pool["k"] = constrain(new_pool["k"], (None, None, "kv_heads", None))
-    new_pool["v"] = constrain(new_pool["v"], (None, None, "kv_heads", None))
+    new_pool["k"] = constrain(new_pool["k"], (None, "kv_heads", None, None))
+    new_pool["v"] = constrain(new_pool["v"], (None, "kv_heads", None, None))
     if quant:
-        new_pool["ks"] = constrain(new_pool["ks"], (None, None, "kv_heads"))
-        new_pool["vs"] = constrain(new_pool["vs"], (None, None, "kv_heads"))
+        new_pool["ks"] = constrain(new_pool["ks"], (None, "kv_heads", None))
+        new_pool["vs"] = constrain(new_pool["vs"], (None, "kv_heads", None))
 
     g = cfg.num_heads // cfg.num_kv_heads
     nm = (lambda s_: None) if name is None else name
@@ -393,17 +396,16 @@ def attention_chunk_paged(p, pool, page_table, x, cfg, *, pos, rpos=None,
     # (the gathered view inherits the pool's head sharding, so each device
     # gathers and attends only its local heads — the reference semantics
     # of the shard_map'd kernel above)
+    from repro.kernels.ref import gather_pages
     s_slot = page_table.shape[1] * page_size
-    ck = new_pool["k"][page_table].reshape(b, s_slot, cfg.num_kv_heads,
-                                           cfg.head_dim)
-    cv = new_pool["v"][page_table].reshape(b, s_slot, cfg.num_kv_heads,
-                                           cfg.head_dim)
+    ck = gather_pages(new_pool["k"], page_table)
+    cv = gather_pages(new_pool["v"], page_table)
     ck = constrain(ck, ("batch", None, "kv_heads", None))
     cv = constrain(cv, ("batch", None, "kv_heads", None))
     adt = jnp.dtype(cfg.activation_dtype)
     if quant:
-        ks = new_pool["ks"][page_table].reshape(b, s_slot, cfg.num_kv_heads)
-        vs = new_pool["vs"][page_table].reshape(b, s_slot, cfg.num_kv_heads)
+        ks = gather_pages(new_pool["ks"], page_table)
+        vs = gather_pages(new_pool["vs"], page_table)
         ck = _kv_dequant(ck, ks, adt)
         cv = _kv_dequant(cv, vs, adt)
     k_pos = jnp.broadcast_to(jnp.arange(s_slot)[None, :], (b, s_slot))

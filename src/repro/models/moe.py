@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.packing import PackedLinear, dequantize_packed
-from repro.distributed import shard_map
 from repro.models import layers
 from repro.models.layers import activation, linear
 
@@ -293,7 +292,7 @@ def moe_apply(p, x: jax.Array, cfg, name=None) -> tuple[jax.Array, jax.Array]:
                 return y_l[None]
 
             wsp = P(None, None, "model")
-            y = shard_map(
+            y = jax.shard_map(
                 body_q, mesh=mesh,
                 in_specs=(P(dp), P(dp), P(dp),
                           wsp, wsp, wsp, P(),
@@ -322,7 +321,7 @@ def moe_apply(p, x: jax.Array, cfg, name=None) -> tuple[jax.Array, jax.Array]:
 
             wspec = P(None, None, "model") if has_model else P()
             wspec_d = P(None, "model", None) if has_model else P()
-            y = shard_map(
+            y = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(dp), P(dp), P(dp), wspec, wspec, wspec_d),
                 out_specs=P(dp),

@@ -21,10 +21,38 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float               # bf16 FLOP/s per chip
+    hbm_bw: float              # HBM bytes/s per chip
+    ici_bw: float              # interconnect bytes/s per link
+
+
+# Published per-chip peaks, keyed by `jax.Device.device_kind`.
+# "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect over 4 links.
+PEAKS = {"TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9)}
+
+# The chip the analytic (dry-run) terms below are projected onto.
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of the chip a time was measured on. A chip that is not in
+    `PEAKS` is an error, never a default: a measured time divided by
+    another chip's peak is a wrong number."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to PEAKS with their "
+                         f"source") from None
+
+
+PEAK_FLOPS = PEAKS[TARGET_KIND].flops
+HBM_BW = PEAKS[TARGET_KIND].hbm_bw
+ICI_BW = PEAKS[TARGET_KIND].ici_bw
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")

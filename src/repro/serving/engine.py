@@ -552,11 +552,12 @@ class GenerationEngine:
         from repro.distributed import sharding as shd
         mesh = self._mesh
         self._repl_sh = NamedSharding(mesh, P())
-        self._param_sh = shd.make_sharding(self.params, mesh,
-                                           shd.param_pspec, self.cfg)
+        params = shd.tag_tensor_parallel(self.params, mesh, self.cfg)
+        self._param_sh = shd.make_sharding(params, mesh, shd.param_pspec,
+                                           self.cfg)
         self._cache_sh = shd.make_sharding(self._paged_cache, mesh,
                                            shd.paged_cache_pspec)
-        self._params_run = jax.device_put(self.params, self._param_sh)
+        self._params_run = jax.device_put(params, self._param_sh)
         self._paged_cache = jax.device_put(self._paged_cache, self._cache_sh)
 
     # --- host-memory page tier (preemption spill/restore) -----------------
@@ -715,7 +716,7 @@ class GenerationEngine:
         from repro.core.qlinear import execution_config, get_execution_config
         cache: dict = {}
 
-        def call(*args):
+        def active():
             cfg = get_execution_config()
             jitted = cache.get(cfg)
             if jitted is None:
@@ -725,7 +726,12 @@ class GenerationEngine:
 
                 jitted = jax.jit(traced, **jit_kw)
                 cache[cfg] = jitted
-            return jitted(*args)
+            return jitted
+
+        def call(*args):
+            return active()(*args)
+
+        call.lower = lambda *args: active().lower(*args)
 
         # jax.jit's compiled-trace introspection, summed over the config
         # instances (tests bound the compile family through this)
@@ -924,7 +930,7 @@ class GenerationEngine:
         dp = jnp.where(live, dst_pg, 0).reshape(-1)
         do = jnp.where(live, dst_i % ps, 0).reshape(-1)
         return {seg: {"kv_pool": {
-                    k: leaf.at[:, dp, do].set(leaf[:, sp, so])
+                    k: leaf.at[:, dp, :, do].set(leaf[:, sp, :, so])
                     for k, leaf in entry["kv_pool"].items()}}
                 for seg, entry in cache.items()}
 
@@ -1307,6 +1313,36 @@ class GenerationEngine:
                         sub)
                     n += 1
         return n
+
+    def lowered_step(self, width: int | None = None):
+        """The greedy chunk step, lowered (`jax.stages.Lowered`) for a
+        ``[num_slots, width]`` token block (default: the widest bucket)
+        over the largest context bucket, with the all-padding arguments
+        `warmup` dispatches — for inspecting what the compiled serving
+        step contains, e.g. its kernels in ``.compile().as_text()``."""
+        if self._scheduler is None:
+            self._scheduler = self._serving_init()
+        if not self._scheduler.chunked:
+            raise ValueError("the one-shot path has no chunk step")
+        c = width or max(self._scheduler.width_buckets)
+        b = self.num_slots
+        tables = self._device_tables(self._context_bucket(self.max_seq - 1))
+        return self._chunk_greedy.lower(
+            self._params_run, self._paged_cache, tables,
+            jnp.zeros((b, c), jnp.int32), jnp.full((b, c), -1, jnp.int32),
+            jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32))
+
+    def placement(self) -> dict[str, set]:
+        """Devices holding the serving params and the page pools (the
+        serving state exists after the first `submit` or `warmup`)."""
+        if self._scheduler is None:
+            self._scheduler = self._serving_init()
+
+        def devs(tree):
+            return {d for x in jax.tree.leaves(tree) for d in x.devices()}
+
+        return {"params": devs(self._params_run),
+                "pools": devs(self._paged_cache)}
 
     def _exec_prefill_commit(self, req: Request, slot: int,
                              pages: list[int], n_shared: int = 0) -> int:

@@ -918,7 +918,8 @@ class KVPager:
 
 def _commit_paged_leaf(pool, pre, phys_pages, page_size: int,
                        start_page: int = 0):
-    """pre [L, 1, S, ...] → scatter into pool [L, num_pages, P, ...].
+    """pre [L, 1, S, Hkv, ...] → scatter into the head-major pool
+    [L, num_pages, Hkv, P, ...].
 
     ``start_page`` skips the leading aliased prefix pages: their content is
     already in the pool (committed by the request that registered the
@@ -938,9 +939,10 @@ def _commit_paged_leaf(pool, pre, phys_pages, page_size: int,
     if full:
         body = pre[:, :full * page_size].reshape(
             (lead, full, page_size) + rest)
-        pool = pool.at[:, pages[:full]].set(body)
+        pool = pool.at[:, pages[:full]].set(jnp.swapaxes(body, 2, 3))
     if rem:
-        pool = pool.at[:, pages[full], :rem].set(pre[:, full * page_size:])
+        pool = pool.at[:, pages[full], :, :rem].set(
+            jnp.swapaxes(pre[:, full * page_size:], 1, 2))
     return pool
 
 

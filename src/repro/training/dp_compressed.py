@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import shard_map
 from repro.distributed.compression import int8_psum_mean
 from repro.training.optim import AdamWConfig, adamw_init, adamw_update
 
@@ -81,7 +80,7 @@ def make_dp_train_step(model, mesh, opt_cfg: AdamWConfig,
                      "step": state["step"] + 1}
         return new_state, ef, {"loss": loss, **opt_metrics}
 
-    step = shard_map(
+    step = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), P(dp_axes), P(dp_axes)),
         out_specs=(P(), P(dp_axes), P()),

@@ -79,6 +79,18 @@ set_execution_config(impl="ref", compute_dtype=jnp.float32)
 sh_s, st_sh = serve(qp, mesh=serving_mesh(2), **FULL)
 out["identical_sharded"] = sh_s == ref_streams["full"]
 out["model_axis"] = st_sh.model_axis
+# the fused kernel per shard (shard_map over the mesh's `model` axis)
+set_execution_config(impl="kernel_interpret", compute_dtype=jnp.float32)
+ker_sh, _ = serve(qp, mesh=serving_mesh(2), **FULL)
+out["identical_sharded_kernel"] = ker_sh == ref_streams["full"]
+from repro.core.packing import PackedLinear
+from repro.distributed.sharding import tag_tensor_parallel
+tagged = tag_tensor_parallel(qp, serving_mesh(2), cfg)
+tps = [x.tp for x in jax.tree.leaves(
+    tagged, is_leaf=lambda x: isinstance(x, PackedLinear))
+    if isinstance(x, PackedLinear)]
+out["tp_tags"] = sorted(set(str(t) for t in tps))
+set_execution_config(impl="ref", compute_dtype=jnp.float32)
 
 # --- weight stream accounting -------------------------------------------
 _, st_q = serve(qp)
@@ -127,3 +139,12 @@ def test_awq_weight_stream_shrinks(result):
     """The per-token weight stream the paper targets actually shrinks."""
     assert result["wbpt_positive"]
     assert result["weight_bytes_awq"] < 0.6 * result["weight_bytes_float"]
+
+
+def test_awq_sharded_kernel_stream_identical(result):
+    """The fused kernel under the 2-way mesh runs per shard —
+    column-parallel layers on their N-shard, row-parallel ones on their
+    K-shard with a sum over ``model`` — and the greedy stream stays
+    identical to the unsharded reference engine."""
+    assert result["tp_tags"] == ["col", "row"]
+    assert result["identical_sharded_kernel"]

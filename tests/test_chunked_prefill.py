@@ -191,9 +191,9 @@ def test_bounded_compile_family_for_all_prompt_lengths(model_and_params):
 ])
 def test_chunk_kernel_matches_oracle(b, c, hkv, g, hd, page, nblk, npages):
     rng = np.random.default_rng(hash((b, c, hkv, g)) % 2**31)
-    kf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)) * 2,
+    kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)) * 2,
                      jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
     k, ks = _kv_quantize(kf)
     v, vs = _kv_quantize(vf)
     q = jnp.asarray(rng.normal(size=(b, c, hkv, g, hd)), jnp.float32)
@@ -213,8 +213,8 @@ def test_chunk_kernel_causal_within_chunk():
     tokens at positions > p even though their KV is already written."""
     rng = np.random.default_rng(0)
     npages, page, hkv, g, hd, nblk = 6, 8, 2, 2, 64, 2
-    kf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
+    kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
     k, ks = _kv_quantize(kf)
     v, vs = _kv_quantize(vf)
     table = jnp.asarray([[2, 4]], jnp.int32)
@@ -234,9 +234,9 @@ def test_chunk_kernel_causal_within_chunk():
 # ---------------------------------------------------------------------------
 
 def _rand_pool(rng, npages, page, hkv, hd):
-    kf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)) * 2,
+    kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)) * 2,
                      jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
     k, ks = _kv_quantize(kf)
     v, vs = _kv_quantize(vf)
     return k, ks, v, vs
@@ -306,10 +306,10 @@ def test_chunk_kernel_tree_mask_straddles_page_boundary():
     # slot past the page boundary): only b itself (node 2) and its child d
     # (node 4) may change — sibling c_sib (node 3) and the b-free prefix
     # must be bit-identical, proving the ancestor mask holds across pages
-    k2 = k.at[6, 0].set(127)
-    v2 = v.at[6, 0].set(127)
-    ks2 = ks.at[6, 0].set(50.0)
-    vs2 = vs.at[6, 0].set(50.0)
+    k2 = k.at[6, :, 0].set(127)
+    v2 = v.at[6, :, 0].set(127)
+    ks2 = ks.at[6, :, 0].set(50.0)
+    vs2 = vs.at[6, :, 0].set(50.0)
     out2 = paged_attention_chunk(q, k2, ks2, v2, vs2, table, pos,
                                  rpos=rpos, amask=jnp.asarray(amask),
                                  interpret=True)

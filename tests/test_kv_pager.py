@@ -303,8 +303,8 @@ def test_commit_scatter_matches_logical_order():
     k = rng.normal(size=(layers, 1, s, heads, hd)).astype(np.float32)
     v = rng.normal(size=(layers, 1, s, heads, hd)).astype(np.float32)
     cache = {"seg_0": {"kv_pool": {
-        "k": jnp.zeros((layers, n_pages, page_size, heads, hd)),
-        "v": jnp.zeros((layers, n_pages, page_size, heads, hd))}}}
+        "k": jnp.zeros((layers, n_pages, heads, page_size, hd)),
+        "v": jnp.zeros((layers, n_pages, heads, page_size, hd))}}}
     prefill = {"seg_0": {"kv": {"k": jnp.asarray(k), "v": jnp.asarray(v)}}}
     phys = jnp.asarray([5, 2], jnp.int32)
     out = commit_prefill(cache, prefill, jnp.int32(0), phys,
@@ -312,7 +312,8 @@ def test_commit_scatter_matches_logical_order():
     pool = out["seg_0"]["kv_pool"]["k"]
     table = np.zeros((1, pages_per_slot), np.int32)
     table[0, :2] = [5, 2]
-    gathered = np.asarray(pool)[:, table[0]].reshape(layers, -1, heads, hd)
+    gathered = np.asarray(pool)[:, table[0]].swapaxes(2, 3).reshape(
+        layers, -1, heads, hd)
     np.testing.assert_array_equal(gathered[:, :s], k[:, 0])
     # pages not owned by the slot stay zero
     untouched = [i for i in range(n_pages) if i not in (5, 2)]

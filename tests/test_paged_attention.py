@@ -20,9 +20,9 @@ from repro.serving.kv_pager import commit_prefill
 ])
 def test_kernel_matches_oracle(b, hkv, g, hd, page, nblk, npages):
     rng = np.random.default_rng(hash((b, hkv, g)) % 2**31)
-    kf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)) * 2,
+    kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)) * 2,
                      jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
     k, ks = _kv_quantize(kf)
     v, vs = _kv_quantize(vf)
     q = jnp.asarray(rng.normal(size=(b, hkv, g, hd)), jnp.float32)
@@ -38,8 +38,8 @@ def test_kernel_masks_stale_table_entries():
     positions exceed pos so they must never leak into the softmax."""
     rng = np.random.default_rng(0)
     npages, page, hkv, hd, nblk = 8, 8, 2, 64, 4
-    kf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
+    kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
     k, ks = _kv_quantize(kf)
     v, vs = _kv_quantize(vf)
     q = jnp.asarray(rng.normal(size=(1, hkv, 2, hd)), jnp.float32)
@@ -61,8 +61,8 @@ def test_decode_paged_jnp_path_matches_kernel():
     npages, page, nblk = 9, 8, 3
     hkv, hd = cfg.num_kv_heads, cfg.head_dim
     g = cfg.num_heads // hkv
-    kf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(npages, page, hkv, hd)), jnp.float32)
+    kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
     k, ks = _kv_quantize(kf)
     v, vs = _kv_quantize(vf)
     q = jnp.asarray(rng.normal(size=(2, hkv, g, hd)), jnp.float32)
@@ -88,10 +88,10 @@ def test_decode_paged_jnp_path_matches_kernel():
 # ---------------------------------------------------------------------------
 
 def _int8_pool(layers, n_pages, page, heads, hd):
-    return {"k": jnp.zeros((layers, n_pages, page, heads, hd), jnp.int8),
-            "v": jnp.zeros((layers, n_pages, page, heads, hd), jnp.int8),
-            "ks": jnp.zeros((layers, n_pages, page, heads), jnp.float32),
-            "vs": jnp.zeros((layers, n_pages, page, heads), jnp.float32)}
+    return {"k": jnp.zeros((layers, n_pages, heads, page, hd), jnp.int8),
+            "v": jnp.zeros((layers, n_pages, heads, page, hd), jnp.int8),
+            "ks": jnp.zeros((layers, n_pages, heads, page), jnp.float32),
+            "vs": jnp.zeros((layers, n_pages, heads, page), jnp.float32)}
 
 
 def test_commit_quantizes_float_prefill_into_int8_pages():
@@ -109,16 +109,17 @@ def test_commit_quantizes_float_prefill_into_int8_pages():
     pool = out["seg_0"]["kv_pool"]
     table = np.asarray([4, 2, 6])
     for name, scale_name, ref in (("k", "ks", k), ("v", "vs", v)):
-        codes = np.asarray(pool[name])[:, table].reshape(layers, -1, heads, hd)
-        scales = np.asarray(pool[scale_name])[:, table].reshape(layers, -1,
-                                                                heads)
+        codes = np.asarray(pool[name])[:, table].swapaxes(2, 3).reshape(
+            layers, -1, heads, hd)
+        scales = np.asarray(pool[scale_name])[:, table].swapaxes(
+            2, 3).reshape(layers, -1, heads)
         deq = codes.astype(np.float32) * scales[..., None]
         err = np.abs(deq[:, :s] - ref[:, 0])
         bound = scales[:, :s, :, None] * 0.5 + 1e-6
         assert (err <= bound).all(), (name, err.max())
     # the zero row round-trips exactly
-    deq_k = (np.asarray(pool["k"])[0, 4, 3].astype(np.float32)
-             * np.asarray(pool["ks"])[0, 4, 3][..., None])
+    deq_k = (np.asarray(pool["k"])[0, 4, :, 3].astype(np.float32)
+             * np.asarray(pool["ks"])[0, 4, :, 3][..., None])
     assert np.abs(deq_k).max() == 0.0
 
 

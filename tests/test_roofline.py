@@ -90,3 +90,15 @@ def test_analytic_costmodel_sanity():
     # train flops >> decode flops
     cc_t = cell_costs(cfg, SHAPES["train_4k"], quant=False)
     assert cc_t.flops > 1000 * cc_f.flops
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """Peaks come from a table keyed by `device_kind`; a chip that is not
+    in it is an error, not a silent v5e default."""
+    import pytest
+
+    from repro.roofline.analysis import PEAK_FLOPS, peaks
+    assert peaks("TPU v5 lite").flops == PEAK_FLOPS == 197e12
+    assert peaks("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")

@@ -241,9 +241,9 @@ import json
 import jax
 import numpy as np
 import repro.configs as C
-from repro.distributed import serving_mesh
+from repro.launch.specs import FleetSpec, ReplicaSpec
 from repro.models import build_model
-from repro.serving import GenerationEngine, Router
+from repro.serving import GenerationEngine
 
 cfg = dataclasses.replace(C.get_smoke_config("qwen25-05b"),
                           num_heads=8, num_kv_heads=4, head_dim=16)
@@ -265,9 +265,18 @@ rout = ref_eng.drain()
 want = [[int(t) for t in rout[r]] for r in refs]
 
 # two TP-2 replicas: each owns half the forced-4-device pool's devices
-fleet = Router([GenerationEngine(m, params, mesh=serving_mesh(2), **KW)
-                for _ in range(2)])
+fleet = FleetSpec(replicas=2, replica=ReplicaSpec(
+    mesh_axis=2, engine_kwargs=KW)).build(m, params)
 out["model_axes"] = [s.model_axis for s in fleet.stats()]
+out["replica_devices"] = [
+    {k: sorted(d.id for d in v) for k, v in r.placement().items()}
+    for r in fleet.replicas]
+# a disaggregated replica: prefill and decode engines on their own pairs
+ctrl = ReplicaSpec(disagg=True, prefill_mesh_axis=2, decode_mesh_axis=2,
+                   engine_kwargs=KW).build(m, params, devices=jax.devices())
+out["disagg_devices"] = [
+    sorted(d.id for d in side.engine.placement()["pools"])
+    for side in (ctrl.prefill, ctrl.decode)]
 rids = [fleet.submit(p, 10, prefix_id="sys") for p in prompts]
 fout = fleet.drain()
 out["identical"] = [[int(t) for t in fout[r]] for r in rids] == want
@@ -295,3 +304,12 @@ def test_two_tp2_replicas_match_unsharded_engine(sharded_result):
     assert sharded_result["model_axes"] == [2, 2]
     assert sharded_result["identical"]
     assert sharded_result["spread"]
+
+
+def test_fleet_places_replicas_on_disjoint_devices(sharded_result):
+    """`FleetSpec.build` hands each TP-2 replica its own device pair, and
+    a disaggregated replica's prefill and decode meshes do not overlap."""
+    assert sharded_result["replica_devices"] == [
+        {"params": [0, 1], "pools": [0, 1]},
+        {"params": [2, 3], "pools": [2, 3]}]
+    assert sharded_result["disagg_devices"] == [[0, 1], [2, 3]]

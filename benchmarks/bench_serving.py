@@ -48,8 +48,7 @@ Scenario sections:
   * **decode-row packing** — every row of the unified dispatch declares
     its true run length and the packer pads only to the smallest width
     bucket covering the step; reported as the padding-waste % of
-    dispatched positions, next to what the old fixed-chunk-width policy
-    would have paid on the same steps.
+    dispatched positions.
   * **tiered SLO (preemption + KV spill)** — two overload shapes against
     the same engine, TTFT measured in *dispatch steps* (deterministic
     under greedy, so the smoke gate asserts improvements instead of
@@ -714,18 +713,13 @@ def verify_token_identity(m, params, workload, identity):
 def _padding_rows(st, csv_rows, tag="serving/padding"):
     """Decode-row packing accounting from a burst's `EngineStats`: rows
     declare their true run length, so padding is paid only up to the
-    step's width bucket — reported next to what the old policy (every
-    row padded to the prefill chunk width whenever anything prefills)
-    would have paid on the same steps."""
-    waste, waste_fixed = st.padding_waste, st.padding_waste_fixed
-    csv_rows.extend([
+    step's width bucket."""
+    waste = st.padding_waste
+    csv_rows.append(
         (f"{tag}_waste", f"{waste:.1%}",
          "share of dispatched positions holding padding "
-         "(run-length packer)"),
-        (f"{tag}_waste_fixed_width", f"{waste_fixed:.1%}",
-         "same steps under the old pad-to-chunk-width policy"),
-    ])
-    return {"waste": waste, "waste_fixed": waste_fixed}
+         "(run-length packer)"))
+    return {"waste": waste}
 
 
 # ---------------------------------------------------------------------------
@@ -1714,8 +1708,6 @@ if __name__ == "__main__":
     assert par["identical"]
     assert par["shared_pages"] > 0 and par["sep_shared"] == 0
     assert par["skipped"] > 0
-    # run-length packing can only remove padding vs the fixed-width policy
-    assert out["padding"]["waste"] <= out["padding"]["waste_fixed"] + 1e-9
     # the packed weight stream must actually be smaller than the float one
     assert out["awq"]["weight_bytes"]["awq"] \
         < out["awq"]["weight_bytes"]["float"]

@@ -50,6 +50,7 @@ import numpy as np
 from repro.serving.engine import GenerationEngine, SamplerConfig
 from repro.serving.kv_pager import HandoffRecord, PageAllocationError
 from repro.serving.scheduler import Request
+from repro.serving.tracing import SpanStats, span
 
 # constructor kwargs stripped from the prefill side: it parks at the
 # first sampled token, so drafting/verification machinery would only
@@ -78,17 +79,31 @@ class KVHandoff:
 
 
 @dataclasses.dataclass
-class DisaggStats:
+class DisaggStats(SpanStats):
+    """The controller's counters, and its spans (`SpanStats`):
+    ``disagg.prefill_step``, ``disagg.decode_step`` and ``disagg.adopt``."""
     handoffs: int = 0            # requests adopted by the decode engine
     handoff_pages: int = 0       # logical pages shipped
     aliased_pages: int = 0       # shipped pages the decode pool already
                                  # held (prefix index hit — zero wire cost)
     wire_bytes: int = 0          # host-side bytes actually transferred
-    adopt_time_s: float = 0.0    # wire + scatter + re-admission wall time
-                                 # (the decode-side TTFT-as-transfer cost)
     direct: int = 0              # requests served whole by the decode side
-    prefill_step_time_s: float = 0.0   # wall inside prefill dispatches
-    decode_step_time_s: float = 0.0    # wall inside decode dispatches
+
+    @property
+    def adopt_time_s(self) -> float:
+        """Scatter + re-admission wall time of the adopted handoffs (the
+        decode-side TTFT-as-transfer cost)."""
+        return self.span_s.get("disagg.adopt", 0.0)
+
+    @property
+    def prefill_step_time_s(self) -> float:
+        """Wall time inside prefill-engine steps."""
+        return self.span_s.get("disagg.prefill_step", 0.0)
+
+    @property
+    def decode_step_time_s(self) -> float:
+        """Wall time inside decode-engine steps."""
+        return self.span_s.get("disagg.decode_step", 0.0)
 
 
 class PrefillEngine:
@@ -310,19 +325,19 @@ class DisaggController:
         blocking touch of the strips).
         """
         events: list[tuple[int, int]] = []
-        t0 = time.perf_counter()
-        for prid, tok in self.prefill.step():
+        with span(self.stats_, "disagg.prefill_step"):
+            stepped = self.prefill.step()
+        for prid, tok in stepped:
             crid = self._of_prefill.get(prid)
             if crid is not None:
                 events.append((crid, tok))
-        self.stats_.prefill_step_time_s += time.perf_counter() - t0
         self._pending.extend(self.prefill.collect_handoffs())
-        t0 = time.perf_counter()
-        for drid, tok in self.decode.step():
+        with span(self.stats_, "disagg.decode_step"):
+            stepped = self.decode.step()
+        for drid, tok in stepped:
             crid = self._of_decode.get(drid)
             if crid is not None:
                 events.append((crid, tok))
-        self.stats_.decode_step_time_s += time.perf_counter() - t0
         self._adopt_pending()
         return events
 
@@ -330,18 +345,17 @@ class DisaggController:
         still: list[KVHandoff] = []
         for h in self._pending:
             self.prefill.wire(h)
-            t0 = time.perf_counter()
+            st = self.stats_
             try:
-                drid, n_fresh = self.decode.adopt(h)
+                with span(st, "disagg.adopt"):
+                    drid, n_fresh = self.decode.adopt(h)
             except PageAllocationError:
                 still.append(h)     # decode pool full — retry next step
                 continue
-            st = self.stats_
             st.handoffs += 1
             st.handoff_pages += h.record.n_pages
             st.aliased_pages += h.record.n_pages - n_fresh
             st.wire_bytes += h.wire_bytes
-            st.adopt_time_s += time.perf_counter() - t0
             self._of_decode[drid] = self._of_prefill[h.request.rid]
         self._pending = still
 

@@ -77,6 +77,7 @@ import numpy as np
 from repro.serving.kv_pager import (KVPager, PagerConfig, PagerStats,
                                     commit_prefill)
 from repro.serving.scheduler import Request, Scheduler
+from repro.serving.tracing import StepTimes, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,7 +103,6 @@ class EngineStats:
     prefill_tokens_skipped: int   # aliased prompt tokens never re-run
     prefix_shared_pages: int      # pages aliased instead of allocated
     padding_waste: float          # padding / dispatched positions
-    padding_waste_fixed: float    # same steps under pad-to-chunk-width
     # speculative decoding
     acceptance_rate: float
     spec_tokens_per_row: float
@@ -130,6 +130,8 @@ class EngineStats:
     # amortized over spec-accepted tokens per row when speculating.
     weight_bytes: int
     weight_bytes_per_token: float
+    # host spans and per-step counters of the serving step (`StepTimes`)
+    step_times: StepTimes
     # load snapshot (cheap, host-only): what a fleet router needs to score
     # this engine as a placement target without touching scheduler/pager
     # internals. `queue_depth` counts requests waiting for a slot (queued
@@ -1193,59 +1195,44 @@ class GenerationEngine:
 
     def _exec_run_batch(self, tokens, pos, row_slots, sample_idx, temps,
                         topks, n_draft=None, tree=None):
-        tables = self._device_tables(self._context_bucket(int(pos.max())))
+        st = self._scheduler.stats
+        greedy = not temps.any() and not topks.any()
+        spec = tree is not None or (n_draft is not None and n_draft.any())
+        with span(st, "serve.upload"):
+            tables = self._device_tables(self._context_bucket(int(pos.max())))
+            # operands in the order every step variant takes them
+            host = [tokens, pos, row_slots, sample_idx]
+            if spec:
+                host.append(n_draft)
+            if tree is not None:
+                host += [tree["rpos"], tree["amask"], tree["parents"]]
+            if not greedy:
+                host += [temps, topks]
+            args = [jnp.asarray(a) for a in host]
+            if not greedy:
+                self._key, sub = jax.random.split(self._key)
+                args.append(sub)
         if tree is not None:
             # tree verify: per-row ancestor masks + logical positions in,
             # (corrected token, accepted depth, accepted branch) out —
             # the accepted KV is already compacted on device
-            targs = (jnp.asarray(tokens), jnp.asarray(pos),
-                     jnp.asarray(row_slots), jnp.asarray(sample_idx),
-                     jnp.asarray(n_draft), jnp.asarray(tree["rpos"]),
-                     jnp.asarray(tree["amask"]),
-                     jnp.asarray(tree["parents"]))
-            if not temps.any() and not topks.any():
-                fix, n_acc, path, self._paged_cache = self._tree_greedy(
-                    self._params_run, self._paged_cache, tables, *targs)
-            else:
-                self._key, sub = jax.random.split(self._key)
-                fix, n_acc, path, self._paged_cache = self._tree_sampled(
-                    self._params_run, self._paged_cache, tables, *targs,
-                    jnp.asarray(temps), jnp.asarray(topks), sub)
-            return np.asarray(fix), np.asarray(n_acc), np.asarray(path)
-        if n_draft is not None and n_draft.any():
+            fn = self._tree_greedy if greedy else self._tree_sampled
+        elif spec:
             # at least one verify run: the speculative step returns, per
             # row, the leading-accept count + corrected/bonus token
-            if not temps.any() and not topks.any():
-                fix, n_acc, self._paged_cache = self._spec_greedy(
-                    self._params_run, self._paged_cache, tables,
-                    jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(row_slots), jnp.asarray(sample_idx),
-                    jnp.asarray(n_draft))
-            else:
-                self._key, sub = jax.random.split(self._key)
-                fix, n_acc, self._paged_cache = self._spec_sampled(
-                    self._params_run, self._paged_cache, tables,
-                    jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(row_slots), jnp.asarray(sample_idx),
-                    jnp.asarray(n_draft), jnp.asarray(temps),
-                    jnp.asarray(topks), sub)
-            return np.asarray(fix), np.asarray(n_acc)
-        if not temps.any() and not topks.any():
-            out, self._paged_cache = self._chunk_greedy(
-                self._params_run, self._paged_cache, tables,
-                jnp.asarray(tokens), jnp.asarray(pos),
-                jnp.asarray(row_slots), jnp.asarray(sample_idx))
+            fn = self._spec_greedy if greedy else self._spec_sampled
         else:
-            self._key, sub = jax.random.split(self._key)
-            out, self._paged_cache = self._chunk_sampled(
-                self._params_run, self._paged_cache, tables,
-                jnp.asarray(tokens), jnp.asarray(pos),
-                jnp.asarray(row_slots), jnp.asarray(sample_idx),
-                jnp.asarray(temps), jnp.asarray(topks), sub)
-        out = np.asarray(out)
+            fn = self._chunk_greedy if greedy else self._chunk_sampled
+        with span(st, "serve.dispatch"):
+            *out, self._paged_cache = fn(self._params_run, self._paged_cache,
+                                         tables, *args)
+        with span(st, "serve.wait"):
+            out = [np.asarray(o) for o in out]
+        if len(out) > 1:
+            return tuple(out)
         if n_draft is None:
-            return out
-        return out, np.zeros(out.shape[0], np.int32)
+            return out[0]
+        return out[0], np.zeros(out[0].shape[0], np.int32)
 
     def warmup(self, sampled: bool = False) -> int:
         """Precompile the chunked step family: every geometric context
@@ -1357,18 +1344,21 @@ class GenerationEngine:
 
     def _exec_decode(self, page_tables, token, pos, temps, topks
                      ) -> np.ndarray:
-        tables = self._device_tables(host_tables=page_tables)
-        if not temps.any() and not topks.any():
-            next_tok, self._paged_cache = self._decode_greedy(
-                self.params, self._paged_cache, tables,
-                jnp.asarray(token), jnp.asarray(pos))
-        else:
-            self._key, sub = jax.random.split(self._key)
-            next_tok, self._paged_cache = self._decode_paged(
-                self.params, self._paged_cache, tables,
-                jnp.asarray(token), jnp.asarray(pos), jnp.asarray(temps),
-                jnp.asarray(topks), sub)
-        return np.asarray(next_tok)
+        st = self._scheduler.stats
+        greedy = not temps.any() and not topks.any()
+        with span(st, "serve.upload"):
+            tables = self._device_tables(host_tables=page_tables)
+            host = [token, pos] if greedy else [token, pos, temps, topks]
+            args = [jnp.asarray(a) for a in host]
+            if not greedy:
+                self._key, sub = jax.random.split(self._key)
+                args.append(sub)
+        fn = self._decode_greedy if greedy else self._decode_paged
+        with span(st, "serve.dispatch"):
+            next_tok, self._paged_cache = fn(self.params, self._paged_cache,
+                                             tables, *args)
+        with span(st, "serve.wait"):
+            return np.asarray(next_tok)
 
     def submit(self, tokens, max_new_tokens: int,
                sampler: SamplerConfig | None = None,
@@ -1509,8 +1499,6 @@ class GenerationEngine:
                 shard = a.sharding.shard_shape(a.shape) \
                     if hasattr(a, "sharding") else a.shape
                 pool_per_dev += int(np.prod(shard)) * a.dtype.itemsize
-        valid = st.dispatched_positions - st.padded_positions
-        fixed_total = valid + st.padded_positions_fixed
         model_axis = 1 if self._mesh is None \
             else int(self._mesh.shape.get("model", 1))
         pager_stats = self._scheduler.pager.stats()
@@ -1521,8 +1509,6 @@ class GenerationEngine:
             prefill_tokens_skipped=st.prefill_tokens_skipped,
             prefix_shared_pages=st.prefix_shared_pages,
             padding_waste=st.padding_waste,
-            padding_waste_fixed=(st.padded_positions_fixed
-                                 / max(fixed_total, 1)),
             acceptance_rate=st.acceptance_rate,
             spec_tokens_per_row=st.spec_tokens_per_row,
             draft_tokens=st.draft_tokens,
@@ -1545,6 +1531,16 @@ class GenerationEngine:
             weight_bytes=self.weight_stream_bytes(),
             weight_bytes_per_token=self.weight_bytes_per_token(
                 st.spec_tokens_per_row),
+            step_times=StepTimes(
+                spans={n: (c, st.span_s[n], st.span_max_s[n])
+                       for n, c in st.span_count.items()},
+                kinds={k: (c, st.step_kind_s[k])
+                       for k, c in st.step_kind_count.items()},
+                queue_wait_s=st.queue_wait_s, queue_waits=st.queue_waits,
+                prefill_s=st.prefill_s, prefills=st.prefills,
+                pool_pages_drawn=st.pool_pages_drawn,
+                pool_pages_usable=pager_stats.pages_total - 1,
+                dispatches=st.decode_steps),
             queue_depth=(len(self._scheduler.queue)
                          + len(self._scheduler.preempted)),
             admission_headroom=max(

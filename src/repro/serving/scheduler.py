@@ -78,6 +78,7 @@ from typing import Callable
 import numpy as np
 
 from repro.serving.kv_pager import KVPager, SpillRecord
+from repro.serving.tracing import SpanStats, span
 
 
 def ngram_propose(ctx: np.ndarray, k: int, max_n: int = 3,
@@ -209,6 +210,9 @@ class Request:
     eos_id: int = -1              # -1 ⇒ never stops early
     prefix_id: str | None = None  # opt into prefix sharing (namespace key)
     priority: int = 0             # SLO class: higher admits/preempts lower
+    # host clock when the request was made (at submit): queue wait
+    t_submit: float = dataclasses.field(default_factory=time.perf_counter,
+                                        compare=False, repr=False)
 
 
 @dataclasses.dataclass
@@ -221,6 +225,7 @@ class _SlotState:
     # this counter is seeded one short so the final token still runs and
     # produces the first-token logits.
     committed: int = 0
+    t_admit: float = 0.0          # host clock at admission to the slot
 
     @property
     def prefilling(self) -> bool:
@@ -250,7 +255,10 @@ class _Preempted:
 
 
 @dataclasses.dataclass
-class SchedulerStats:
+class SchedulerStats(SpanStats):
+    """Cumulative counters since the last `zero()`, the serving step's
+    span tables (`SpanStats`: ``serve.*``, see `serving.tracing`) among
+    them."""
     admitted: int = 0
     finished: int = 0
     decode_steps: int = 0         # unified dispatches in chunked mode
@@ -269,9 +277,6 @@ class SchedulerStats:
     # --- token-budget packing accounting --------------------------------
     dispatched_positions: int = 0     # num_slots × c summed over steps
     padded_positions: int = 0         # dispatched positions holding padding
-    padded_positions_fixed: int = 0   # what padding the pre-run-length
-    #                                   policy (c = chunk_size whenever
-    #                                   anything prefills) would have paid
     # --- preemption / spill ---------------------------------------------
     preemptions: int = 0          # slots spilled to the host tier
     pressure_spills: int = 0      # of those, spills by the page-pressure
@@ -279,8 +284,27 @@ class SchedulerStats:
     restores: int = 0             # parked requests re-admitted
     spilled_pages: int = 0        # page strips gathered to the host tier
     restored_pages: int = 0       # page strips scattered back
-    restore_time_s: float = 0.0   # wall time inside restore (pager +
-    #                               device scatter), for restore latency
+    # --- per step and per request (host clock) --------------------------
+    step_kind_count: dict = dataclasses.field(default_factory=dict)
+    #   "chunk" | "decode" → dispatching steps whose block carried a
+    #   prompt chunk | only decode / verify rows
+    step_kind_s: dict = dataclasses.field(default_factory=dict)  # their s
+    queue_wait_s: float = 0.0     # Σ submit → admission to a slot
+    queue_waits: int = 0          # requests admitted from the queue
+    prefill_s: float = 0.0        # Σ admission → end of the step that
+    #                               returned the request's first token
+    prefills: int = 0             # requests given their first token
+    pool_pages_drawn: int = 0     # Σ over dispatches of pages drawn
+
+    @property
+    def restore_time_s(self) -> float:
+        """Wall time inside restore (pager + device scatter): the
+        ``serve.restore`` span's total."""
+        return self.span_s.get("serve.restore", 0.0)
+
+    @restore_time_s.setter
+    def restore_time_s(self, seconds: float) -> None:
+        self.span_s["serve.restore"] = seconds
 
     def zero(self) -> None:
         """Reset every declared counter to its default, **in place**.
@@ -447,6 +471,8 @@ class Scheduler:
         self._preempt_seq = 0
         self.finished: dict[int, np.ndarray] = {}
         self.stats = SchedulerStats()
+        self._first_tokens: list[float] = []  # admission times of the
+        #                                        slots given one this step
         # disaggregated serving (see serving.disagg): rids whose first
         # sampled token PARKS the slot for a cross-engine KV handoff
         # instead of decoding here. Parked slots leave `self.slots` but
@@ -528,13 +554,24 @@ class Scheduler:
         Returns ``(rid, token)`` stream events in emission order.
         """
         events: list[tuple[int, int]] = []
-        self._admit(events)
-        if self.slots:
-            if self.chunked:
-                self._step_chunked(events)
-            else:
-                self._decode_once(events)
-            self._admit(events)          # backfill slots freed by EOS now
+        st = self.stats
+        kind = None
+        with span(st, "serve.step") as step:
+            with span(st, "serve.admit"):
+                self._admit(events)
+            if self.slots:
+                kind = (self._step_chunked(events) if self.chunked
+                        else self._decode_once(events))
+                with span(st, "serve.admit"):
+                    self._admit(events)  # backfill slots freed by EOS now
+            now = time.perf_counter()
+            for t_admit in self._first_tokens:
+                st.prefill_s += now - t_admit
+            st.prefills += len(self._first_tokens)
+            self._first_tokens.clear()
+        if kind is not None:
+            st.step_kind_count[kind] = st.step_kind_count.get(kind, 0) + 1
+            st.step_kind_s[kind] = st.step_kind_s.get(kind, 0.0) + step.s
         return events
 
     def run(self) -> dict[int, np.ndarray]:
@@ -606,8 +643,11 @@ class Scheduler:
         slot, pages = self.pager.alloc_slot(len(req.tokens),
                                             req.max_new_tokens,
                                             shared_pages=shared)
+        now = time.perf_counter()
         self.stats.prefix_shared_pages += len(shared)
         self.stats.admitted += 1
+        self.stats.queue_wait_s += now - req.t_submit
+        self.stats.queue_waits += 1
         if self.chunked:
             # aliased tokens are already resident: chunking starts past
             # them (at least the final prompt token always runs, so the
@@ -615,7 +655,7 @@ class Scheduler:
             skip = min(len(shared) * self.pager.cfg.page_size,
                        len(req.tokens) - 1)
             self.slots[slot] = _SlotState(request=req, generated=[],
-                                          committed=skip)
+                                          committed=skip, t_admit=now)
             self.stats.prefill_tokens_skipped += skip
             return
         # one-shot: fused prefill + commit + first-token sample now
@@ -623,8 +663,9 @@ class Scheduler:
         if req.prefix_id is not None:
             self.pager.register_prefix(slot, req.tokens, req.prefix_id)
         st = _SlotState(request=req, generated=[tok],
-                        committed=len(req.tokens))
+                        committed=len(req.tokens), t_admit=now)
         self.slots[slot] = st
+        self._first_tokens.append(now)
         events.append((req.rid, tok))
         if st.done:
             self._finish(slot)
@@ -684,11 +725,10 @@ class Scheduler:
         watermark came back with the record, so nothing re-prefills."""
         if not self.pager.can_restore(p.record):
             return False
-        t0 = time.perf_counter()
-        slot, fresh = self.pager.restore(p.record)
-        if self._restore_fn is not None and p.handle is not None:
-            self._restore_fn(p.handle, fresh)
-        self.stats.restore_time_s += time.perf_counter() - t0
+        with span(self.stats, "serve.restore"):
+            slot, fresh = self.pager.restore(p.record)
+            if self._restore_fn is not None and p.handle is not None:
+                self._restore_fn(p.handle, fresh)
         self.stats.restores += 1
         self.stats.restored_pages += len(fresh)
         self.slots[slot] = p.state
@@ -794,7 +834,7 @@ class Scheduler:
         return out
 
     # ------------------------------------------- chunked (token-budget) step
-    def _step_chunked(self, events: list[tuple[int, int]]) -> None:
+    def _step_chunked(self, events: list[tuple[int, int]]) -> str | None:
         """One fixed-shape dispatch packing prefill chunks + token runs.
 
         The dispatch is a ``[num_slots, c]`` token block — the step's
@@ -814,198 +854,207 @@ class Scheduler:
         is in flight, and pure-decode steps narrow to ``c = 1`` (or the
         verify-run bucket). The compiled-variant family stays bounded at
         `width_family` × context buckets.
+
+        Returns the step's kind: ``"chunk"`` when the block carried a
+        prompt chunk, ``"decode"`` when it held only decode / verify
+        rows, None when pressure relief left nothing to dispatch.
         """
-        b = self.num_slots
-        drafts = self._propose_drafts() if self.spec_decode is not None \
-            else {}
-        if self.preemption:
-            # optimistic admission: make sure this step's extends fit the
-            # free pool BEFORE packing rows (victims lose their row)
-            self._relieve_pressure(drafts)
-            if not self.slots:
-                return
-        prefilling = [s for s, st in self.slots.items() if st.prefilling]
-        want = 1
-        for slot, st in self.slots.items():
-            if not st.prefilling:
-                want = max(want, 1 + len(drafts.get(slot, ())))
-        if prefilling:
-            want = max(want, max(
-                min(self.chunk_size,
-                    len(self.slots[s].request.tokens)
-                    - self.slots[s].committed) for s in prefilling))
-        c = next(w for w in self.width_buckets if w >= want)
-        tokens = np.zeros((b, c), np.int32)
-        pos = np.full((b, c), -1, np.int32)
-        row_slots = np.zeros(b, np.int32)
-        temps = np.zeros(b, np.float32)
-        topks = np.zeros(b, np.int32)
-        sample_idx = np.zeros(b, np.int32)
-        n_draft = np.zeros(b, np.int32)
-        sample_row: dict[int, int] = {}       # slot → row holding its sample
-        chunk_tok: dict[int, int] = {}        # slot → prompt tokens this step
-        run_q: dict[int, int] = {}            # slot → base pos of its run
-        row_draft: dict[int, list] = {}       # slot → drafts in its run
-        tree_rows: dict[int, tuple] = {}      # row → packed tree metadata
-        row = 0
-        for slot, st in self.slots.items():   # decode/verify rows first
-            if st.prefilling:
-                continue
-            r = st.request
-            d = drafts.get(slot, [])
-            n = 1 + len(d)
-            q = st.next_pos
-            tokens[row, 0] = st.generated[-1]
-            if d and self.spec_tree:
-                # tree verify row: node i sits at KV slot q + 1 + i (the
-                # pager's extend/truncate stay contiguous), its LOGICAL
-                # position is q + depth(i) (siblings share a depth, not a
-                # slot), and the ancestor closure becomes the row's
-                # intra-chunk visibility block
-                tokens[row, 1:n] = [t for t, _ in d]
-                dep = np.zeros(n, np.int32)
-                anc = np.zeros((n, n), bool)
-                anc[0, 0] = True
-                par_inrow = np.full(n, -1, np.int32)
-                for i, (_t, par) in enumerate(d):
-                    j = 1 + i
-                    pj = 1 + par if par >= 0 else 0
-                    par_inrow[j] = pj
-                    dep[j] = dep[pj] + 1
-                    anc[j] = anc[pj]
-                    anc[j, j] = True
-                tree_rows[row] = (n, q, dep, anc, par_inrow)
-            elif d:
-                tokens[row, 1:n] = d
-            pos[row, :n] = np.arange(q, q + n)
-            row_slots[row] = slot
-            self.pager.extend(slot, q + n)
-            sample_row[slot] = row
-            run_q[slot] = q
-            row_draft[slot] = d
-            n_draft[row] = len(d)
-            temps[row] = r.temperature
-            topks[row] = r.top_k
-            row += 1
-        for slot in prefilling:               # pack chunks into free rows
-            if row >= b:
-                break
-            st = self.slots[slot]
-            r = st.request
-            start = st.committed
-            take = min(len(r.tokens) - start, (b - row) * c)
-            done = 0
-            while done < take:
-                n = min(c, take - done)
-                tokens[row, :n] = r.tokens[start + done:start + done + n]
-                pos[row, :n] = np.arange(start + done, start + done + n)
+        with span(self.stats, "serve.plan"):
+            b = self.num_slots
+            drafts = (self._propose_drafts()
+                      if self.spec_decode is not None else {})
+            if self.preemption:
+                # optimistic admission: make sure this step's extends fit
+                # the free pool BEFORE packing rows (victims lose their row)
+                self._relieve_pressure(drafts)
+                if not self.slots:
+                    return None
+            prefilling = [s for s, st in self.slots.items() if st.prefilling]
+            want = 1
+            for slot, st in self.slots.items():
+                if not st.prefilling:
+                    want = max(want, 1 + len(drafts.get(slot, ())))
+            if prefilling:
+                want = max(want, max(
+                    min(self.chunk_size,
+                        len(self.slots[s].request.tokens)
+                        - self.slots[s].committed) for s in prefilling))
+            c = next(w for w in self.width_buckets if w >= want)
+            tokens = np.zeros((b, c), np.int32)
+            pos = np.full((b, c), -1, np.int32)
+            row_slots = np.zeros(b, np.int32)
+            temps = np.zeros(b, np.float32)
+            topks = np.zeros(b, np.int32)
+            sample_idx = np.zeros(b, np.int32)
+            n_draft = np.zeros(b, np.int32)
+            sample_row: dict[int, int] = {}   # slot → row holding its sample
+            chunk_tok: dict[int, int] = {}    # slot → prompt tokens now
+            run_q: dict[int, int] = {}        # slot → base pos of its run
+            row_draft: dict[int, list] = {}   # slot → drafts in its run
+            tree_rows: dict[int, tuple] = {}  # row → packed tree metadata
+            row = 0
+            for slot, st in self.slots.items():   # decode/verify rows first
+                if st.prefilling:
+                    continue
+                r = st.request
+                d = drafts.get(slot, [])
+                n = 1 + len(d)
+                q = st.next_pos
+                tokens[row, 0] = st.generated[-1]
+                if d and self.spec_tree:
+                    # tree verify row: node i sits at KV slot q + 1 + i (the
+                    # pager's extend/truncate stay contiguous), its LOGICAL
+                    # position is q + depth(i) (siblings share a depth, not a
+                    # slot), and the ancestor closure becomes the row's
+                    # intra-chunk visibility block
+                    tokens[row, 1:n] = [t for t, _ in d]
+                    dep = np.zeros(n, np.int32)
+                    anc = np.zeros((n, n), bool)
+                    anc[0, 0] = True
+                    par_inrow = np.full(n, -1, np.int32)
+                    for i, (_t, par) in enumerate(d):
+                        j = 1 + i
+                        pj = 1 + par if par >= 0 else 0
+                        par_inrow[j] = pj
+                        dep[j] = dep[pj] + 1
+                        anc[j] = anc[pj]
+                        anc[j, j] = True
+                    tree_rows[row] = (n, q, dep, anc, par_inrow)
+                elif d:
+                    tokens[row, 1:n] = d
+                pos[row, :n] = np.arange(q, q + n)
                 row_slots[row] = slot
-                self.stats.prefill_chunks += 1
-                done += n
-                if start + done == len(r.tokens):
-                    sample_row[slot] = row    # last chunk lands this step
-                    sample_idx[row] = n - 1
-                    temps[row] = r.temperature
-                    topks[row] = r.top_k
+                self.pager.extend(slot, q + n)
+                sample_row[slot] = row
+                run_q[slot] = q
+                row_draft[slot] = d
+                n_draft[row] = len(d)
+                temps[row] = r.temperature
+                topks[row] = r.top_k
                 row += 1
-            self.pager.commit_chunk(slot, start, start + take)
-            chunk_tok[slot] = take
-        valid = int((pos >= 0).sum())
-        c_fixed = max(c, self.chunk_size) if prefilling else c
-        self.stats.dispatched_positions += b * c
-        self.stats.padded_positions += b * c - valid
-        self.stats.padded_positions_fixed += b * c_fixed - valid
+            for slot in prefilling:               # pack chunks into free rows
+                if row >= b:
+                    break
+                st = self.slots[slot]
+                r = st.request
+                start = st.committed
+                take = min(len(r.tokens) - start, (b - row) * c)
+                done = 0
+                while done < take:
+                    n = min(c, take - done)
+                    tokens[row, :n] = r.tokens[start + done:start + done + n]
+                    pos[row, :n] = np.arange(start + done, start + done + n)
+                    row_slots[row] = slot
+                    self.stats.prefill_chunks += 1
+                    done += n
+                    if start + done == len(r.tokens):
+                        sample_row[slot] = row    # last chunk lands this step
+                        sample_idx[row] = n - 1
+                        temps[row] = r.temperature
+                        topks[row] = r.top_k
+                    row += 1
+                self.pager.commit_chunk(slot, start, start + take)
+                chunk_tok[slot] = take
+            valid = int((pos >= 0).sum())
+            self.stats.dispatched_positions += b * c
+            self.stats.padded_positions += b * c - valid
+            tree = None
+            if tree_rows:
+                # tree verify: rpos carries logical (depth) positions, amask
+                # the per-row ancestor closure (plain causality elsewhere),
+                # parents the in-row walk topology. The executor returns the
+                # deepest accepted path as in-row node indices.
+                rpos = pos.copy()
+                amask = np.broadcast_to(np.tril(np.ones((c, c), bool)),
+                                        (b, c, c)).copy()
+                parents = np.full((b, c), -1, np.int32)
+                for trow, (n, q, dep, anc, par_inrow) in tree_rows.items():
+                    rpos[trow, :n] = q + dep
+                    amask[trow] = False
+                    amask[trow, :n, :n] = anc
+                    parents[trow, :n] = par_inrow
+                tree = {"rpos": rpos, "amask": amask, "parents": parents}
+            self.stats.pool_pages_drawn += self.pager.pages_in_use
         path_arr = None
         if self.spec_decode is None:
-            sampled = self._run_batch(tokens, pos, row_slots, sample_idx,
+            fix_tok = self._run_batch(tokens, pos, row_slots, sample_idx,
                                       temps, topks)
-            fix_tok, n_acc = sampled, np.zeros(b, np.int32)
-        elif tree_rows:
-            # tree verify: rpos carries logical (depth) positions, amask
-            # the per-row ancestor closure (plain causality elsewhere),
-            # parents the in-row walk topology. The executor returns the
-            # deepest accepted path as in-row node indices.
-            rpos = pos.copy()
-            amask = np.broadcast_to(np.tril(np.ones((c, c), bool)),
-                                    (b, c, c)).copy()
-            parents = np.full((b, c), -1, np.int32)
-            for trow, (n, q, dep, anc, par_inrow) in tree_rows.items():
-                rpos[trow, :n] = q + dep
-                amask[trow] = False
-                amask[trow, :n, :n] = anc
-                parents[trow, :n] = par_inrow
+            n_acc = np.zeros(b, np.int32)
+        elif tree is not None:
             fix_tok, n_acc, path_arr = self._run_batch(
                 tokens, pos, row_slots, sample_idx, temps, topks,
-                n_draft=n_draft,
-                tree={"rpos": rpos, "amask": amask, "parents": parents})
+                n_draft=n_draft, tree=tree)
         else:
             fix_tok, n_acc = self._run_batch(tokens, pos, row_slots,
                                              sample_idx, temps, topks,
                                              n_draft=n_draft)
-        self.stats.decode_steps += 1
-        self.stats.slot_steps += b
-        step_drafted = step_accepted = 0
-        for slot in list(self.slots):
-            st = self.slots[slot]
-            if slot in chunk_tok:
-                st.committed += chunk_tok[slot]
-                self.stats.prefill_tokens += chunk_tok[slot]
-            row = sample_row.get(slot)
-            if row is None or st.prefilling:
-                continue                      # mid-prefill: nothing sampled
-            first = slot in chunk_tok         # prompt completed this step
-            if first and st.request.prefix_id is not None:
-                # register on the final chunk: the whole prompt is resident
-                self.pager.register_prefix(slot, st.request.tokens,
-                                           st.request.prefix_id)
-            if first:
-                tok = int(fix_tok[row])
-                st.generated.append(tok)
-                events.append((st.request.rid, tok))
+        with span(self.stats, "serve.commit"):
+            self.stats.decode_steps += 1
+            self.stats.slot_steps += b
+            step_drafted = step_accepted = 0
+            for slot in list(self.slots):
+                st = self.slots[slot]
+                if slot in chunk_tok:
+                    st.committed += chunk_tok[slot]
+                    self.stats.prefill_tokens += chunk_tok[slot]
+                row = sample_row.get(slot)
+                if row is None or st.prefilling:
+                    continue                  # mid-prefill: nothing sampled
+                first = slot in chunk_tok         # prompt completed this step
+                if first and st.request.prefix_id is not None:
+                    # register on the final chunk: the whole prompt is resident
+                    self.pager.register_prefix(slot, st.request.tokens,
+                                               st.request.prefix_id)
+                if first:
+                    tok = int(fix_tok[row])
+                    st.generated.append(tok)
+                    events.append((st.request.rid, tok))
+                    self._first_tokens.append(st.t_admit)
+                    if st.done:
+                        self._finish(slot)
+                    elif st.request.rid in self.handoff_rids:
+                        # disagg handoff point: the prompt's KV is fully
+                        # committed and the first token is sampled — park the
+                        # slot for export instead of decoding here. The pager
+                        # slot stays live (pages intact) until the controller
+                        # gathers its bytes and frees it.
+                        self.slots.pop(slot)
+                        self.ready_handoffs.append((st, slot))
+                    continue
+                # decode / verify row: emit the accepted draft prefix plus the
+                # corrected (rejection) or bonus (full-acceptance) token,
+                # stopping at EOS / budget mid-run. Tree rows read the
+                # accepted tokens off the returned path (in-row node indices,
+                # deepest accepted branch); linear rows off the draft prefix.
+                d = row_draft.get(slot, [])
+                na = min(int(n_acc[row]), len(d))
+                if self.spec_tree and d:
+                    emit = [d[int(path_arr[row, t]) - 1][0] for t in range(na)]
+                else:
+                    emit = d[:na]
+                for tok in emit + [int(fix_tok[row])]:
+                    st.generated.append(tok)
+                    events.append((st.request.rid, tok))
+                    self.stats.slot_tokens += 1
+                    if st.done:
+                        break
+                if d:
+                    self.stats.spec_rows += 1
+                    self.stats.draft_tokens += len(d)
+                    self.stats.accepted_tokens += na
+                    step_drafted += len(d)
+                    step_accepted += na
                 if st.done:
                     self._finish(slot)
-                elif st.request.rid in self.handoff_rids:
-                    # disagg handoff point: the prompt's KV is fully
-                    # committed and the first token is sampled — park the
-                    # slot for export instead of decoding here. The pager
-                    # slot stays live (pages intact) until the controller
-                    # gathers its bytes and frees it.
-                    self.slots.pop(slot)
-                    self.ready_handoffs.append((st, slot))
-                continue
-            # decode / verify row: emit the accepted draft prefix plus the
-            # corrected (rejection) or bonus (full-acceptance) token,
-            # stopping at EOS / budget mid-run. Tree rows read the
-            # accepted tokens off the returned path (in-row node indices,
-            # deepest accepted branch); linear rows off the draft prefix.
-            d = row_draft.get(slot, [])
-            na = min(int(n_acc[row]), len(d))
-            if self.spec_tree and d:
-                emit = [d[int(path_arr[row, t]) - 1][0] for t in range(na)]
-            else:
-                emit = d[:na]
-            for tok in emit + [int(fix_tok[row])]:
-                st.generated.append(tok)
-                events.append((st.request.rid, tok))
-                self.stats.slot_tokens += 1
-                if st.done:
-                    break
-            if d:
-                self.stats.spec_rows += 1
-                self.stats.draft_tokens += len(d)
-                self.stats.accepted_tokens += na
-                step_drafted += len(d)
-                step_accepted += na
-            if st.done:
-                self._finish(slot)
-            elif na < len(d):
-                # rejected suffix: roll the KV watermark (and any pages
-                # drawn for it) back so the cache matches the stream
-                self.stats.rollbacks += 1
-                self.stats.rollback_pages += self.pager.truncate(
-                    slot, run_q[slot] + na + 1)
-        if self.adaptive_spec_k and step_drafted:
-            self._adapt_spec_k(step_accepted / step_drafted)
+                elif na < len(d):
+                    # rejected suffix: roll the KV watermark (and any pages
+                    # drawn for it) back so the cache matches the stream
+                    self.stats.rollbacks += 1
+                    self.stats.rollback_pages += self.pager.truncate(
+                        slot, run_q[slot] + na + 1)
+            if self.adaptive_spec_k and step_drafted:
+                self._adapt_spec_k(step_accepted / step_drafted)
+        return "chunk" if chunk_tok else "decode"
 
     # EMA half-life of one drafting step; hysteresis band so k doesn't
     # flap on a borderline drafter (one bucket move per step, at most)
@@ -1036,30 +1085,34 @@ class Scheduler:
                 self.fanout_cur = max(self.fanout_cur - 1, 1)
 
     # ------------------------------------------------- one-shot decode step
-    def _decode_once(self, events: list[tuple[int, int]]) -> None:
+    def _decode_once(self, events: list[tuple[int, int]]) -> str:
         b = self.num_slots
-        token = np.zeros(b, np.int32)
-        pos = np.zeros(b, np.int32)
-        temps = np.zeros(b, np.float32)
-        topks = np.zeros(b, np.int32)
-        for slot, st in self.slots.items():
-            token[slot] = st.generated[-1]
-            pos[slot] = st.next_pos
-            temps[slot] = st.request.temperature
-            topks[slot] = st.request.top_k
-            self.pager.extend(slot, st.next_pos + 1)
+        with span(self.stats, "serve.plan"):
+            token = np.zeros(b, np.int32)
+            pos = np.zeros(b, np.int32)
+            temps = np.zeros(b, np.float32)
+            topks = np.zeros(b, np.int32)
+            for slot, st in self.slots.items():
+                token[slot] = st.generated[-1]
+                pos[slot] = st.next_pos
+                temps[slot] = st.request.temperature
+                topks[slot] = st.request.top_k
+                self.pager.extend(slot, st.next_pos + 1)
+            self.stats.pool_pages_drawn += self.pager.pages_in_use
         next_tokens = self._decode(self.pager.page_tables, token, pos,
                                    temps, topks)
-        self.stats.decode_steps += 1
-        self.stats.slot_steps += b
-        for slot in list(self.slots):
-            st = self.slots[slot]
-            tok = int(next_tokens[slot])
-            st.generated.append(tok)
-            self.stats.slot_tokens += 1
-            events.append((st.request.rid, tok))
-            if st.done:
-                self._finish(slot)
+        with span(self.stats, "serve.commit"):
+            self.stats.decode_steps += 1
+            self.stats.slot_steps += b
+            for slot in list(self.slots):
+                st = self.slots[slot]
+                tok = int(next_tokens[slot])
+                st.generated.append(tok)
+                self.stats.slot_tokens += 1
+                events.append((st.request.rid, tok))
+                if st.done:
+                    self._finish(slot)
+        return "decode"
 
     def _finish(self, slot: int) -> None:
         st = self.slots.pop(slot)

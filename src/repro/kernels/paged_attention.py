@@ -27,8 +27,13 @@ Layout: q ``[B, C, Hkv, G, hd]`` (head = kv_head·G + group), head-major
 pools ``[N, Hkv, P, hd]`` int8 with scales ``[N, Hkv, P]`` f32, page_table
 ``[B, pages_per_slot]`` int32, pos ``[B, C]`` int32 (inclusive last valid
 absolute position per query; ``-1`` = padding query, fully masked). Grid
-``(B, Hkv, pages_per_slot)``, pages innermost (accumulation axis); the
-C·G query rows of a (batch, kv-head) cell ride the MXU together.
+``(B, ceil(pages_per_slot / ppb))``, page blocks innermost (accumulation
+axis): one step reads ``ppb`` whole pages — every KV head's codes and
+scale strips — builds the visibility mask of the block once, and runs the
+C·G query rows of each KV head over the block's ``ppb·P`` keys on the
+MXU. ``ppb`` (`pages_per_block`) follows from the shapes alone. A step
+costs a fixed overhead besides its bytes, so fewer, larger steps are what
+bring the kernel toward its bandwidth bound.
 
 Off-TPU the wrappers drop to `kernels.ref.paged_attention_chunk_ref`
 (numerically equal up to online-softmax reassociation); interpret mode
@@ -52,14 +57,43 @@ def supported() -> bool:
     return jax.default_backend() == "tpu"
 
 
+# Pages per grid step: at most this many, and fewer where one page's
+# buffers and f32 working set would overrun the VMEM budget below. Each
+# page of a block is four operands of the call (see
+# `paged_attention_chunk`) whose index maps every step variant lowers
+# at set-up. 8 pages halve those operands against 16; on a TPU v5e the
+# kernel then runs as fast for one-token rows and up to a fifth slower
+# for 16-token chunks.
+_MAX_PAGES_PER_BLOCK = 8
+_VMEM_BUDGET_BYTES = 4 * 2**20
+
+
+def pages_per_block(n_pages: int, page_size: int, hkv: int, hd: int,
+                    rows: int) -> int:
+    """Pages one grid step reads, from shapes alone: each page costs its
+    double-buffered int8 K/V codes and f32 scale strips for every KV head,
+    every head's f32 dequantized K/V rows, and one head's score columns."""
+    per_page = (2 * 2 * hkv * page_size * (hd + 4)
+                + 4 * page_size * (2 * hkv * hd + 2 * rows))
+    return max(1, min(n_pages, _MAX_PAGES_PER_BLOCK,
+                      _VMEM_BUDGET_BYTES // per_page))
+
+
 def _paged_attn_kernel(tables_ref, pos_ref, rpos_ref,  # scalar prefetch
-                       q_ref, k_ref, ks_ref, v_ref, vs_ref, am_ref,
-                       o_ref, m_ref, l_ref, acc_ref, *,
-                       page_size: int, n_blocks: int, n_chunk: int,
-                       n_groups: int, scale: float, window: int):
+                       q_ref, *refs, page_size: int, ppb: int,
+                       n_chunk: int, n_groups: int, scale: float,
+                       window: int):
+    # operands: page 0's k, ks, v, vs, then amask, then the k, ks, v, vs
+    # of pages 1..ppb-1 (see `paged_attention_chunk`); then the output and
+    # the online-softmax scratch, one slab per KV head
+    am_ref = refs[4]
+    k_refs, ks_refs, v_refs, vs_refs = zip(
+        refs[:4], *(refs[1 + 4 * i:5 + 4 * i] for i in range(1, ppb)))
+    o_ref, m_ref, l_ref, acc_ref = refs[1 + 4 * ppb:]
+    hkv, gp = q_ref.shape[1], q_ref.shape[2]
+    keys = ppb * page_size
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -67,82 +101,88 @@ def _paged_attn_kernel(tables_ref, pos_ref, rpos_ref,  # scalar prefetch
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # [gp, hd]
-    k = k_ref[0, 0].astype(jnp.float32)                    # [P, hd] codes
-    v = v_ref[0, 0].astype(jnp.float32)
-    # fused dequant, VMEM-only: the per-(head, position) scale is constant
-    # along hd, so it scales score columns (K) and probability columns (V)
-    # as a [1, P] row instead of rescaling the [P, hd] code tile. The
-    # scale block holds every head's strip ([Hkv, P], a legal tile);
-    # this cell's row is picked with a mask-and-sum over the head axis.
-    ks_all = ks_ref[0].astype(jnp.float32)                 # [Hkv, P]
-    vs_all = vs_ref[0].astype(jnp.float32)
-    if ks_all.shape[0] == 1:
-        ks_row, vs_row = ks_all, vs_all
-    else:
-        mine = jax.lax.broadcasted_iota(jnp.int32, ks_all.shape, 0) == h
-        ks_row = jnp.sum(jnp.where(mine, ks_all, 0.0), axis=0, keepdims=True)
-        vs_row = jnp.sum(jnp.where(mine, vs_all, 0.0), axis=0, keepdims=True)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) \
-        * (ks_row * scale)
-    # per-row query position: row r of the q block is chunk token r // G.
-    # pos lives in SMEM (scalar prefetch); a vector gather out of SMEM is
-    # not expressible, so select it with a static unroll over the (small,
-    # compile-time) chunk length — padded rows keep -1 and mask everything.
-    rows = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0) \
-        // n_groups                                        # [gp, 1] chunk idx
-    q_pos = jnp.full((s.shape[0], 1), -1, jnp.int32)
+    # the visibility mask of the whole [C·G, ppb·P] block, built once and
+    # shared by every KV head. Row r of the q block is chunk token r // G;
+    # pos lives in SMEM (scalar prefetch) and a vector gather out of SMEM
+    # is not expressible, so it is selected with a static unroll over the
+    # (small, compile-time) chunk length — padded rows keep -1.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (gp, 1), 0) // n_groups
+    q_pos = jnp.full((gp, 1), -1, jnp.int32)
     for cc in range(n_chunk):
         q_pos = jnp.where(rows == cc, pos_ref[b * n_chunk + cc], q_pos)
-    k_pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)                             # [gp, P]
+    k_pos = j * keys + jax.lax.broadcasted_iota(jnp.int32, (gp, keys), 1)
     # three-part visibility (see kernels.ref.chunk_visibility_ref):
     #   committed pages — causal watermark test against the row base
     #   (everything below pos[b, 0] is committed KV), optionally bounded
     #   below by the sliding window on the row's *logical* position;
-    #   in-span keys — the explicit [C, C] ancestor-mask block, selected
-    #   per slot offset with a static unroll (no VMEM gathers on TPU);
-    #   padding rows (q_pos = -1) — masked everywhere.
+    #   in-span keys — the explicit [C, C] ancestor-mask block, routed to
+    #   the key columns by a one-hot [C, ppb·P] of slot offsets on the MXU
+    #   (no VMEM gathers on TPU; exact, one 1 at most per key column);
+    #   padding rows (q_pos = -1) — masked everywhere. Keys past the
+    #   bucket (a last block that overhangs it) lie above the watermark
+    #   and outside the span, so they are masked with the rest.
     base = pos_ref[b * n_chunk]
     committed = k_pos < base
     if window:
-        r_pos = jnp.full((s.shape[0], 1), -1, jnp.int32)
+        r_pos = jnp.full((gp, 1), -1, jnp.int32)
         for cc in range(n_chunk):
             r_pos = jnp.where(rows == cc, rpos_ref[b * n_chunk + cc], r_pos)
         committed = committed & (k_pos > r_pos - window)
-    am = am_ref[0]                                         # [gp, C] f32
-    in_span = jnp.zeros(s.shape, jnp.bool_)
-    for t in range(n_chunk):
-        in_span = in_span | ((k_pos == base + t) & (am[:, t:t + 1] > 0.5))
-    s = jnp.where((q_pos >= 0) & (committed | in_span), s, NEG_INF)
+    offset = (j * keys - base
+              + jax.lax.broadcasted_iota(jnp.int32, (n_chunk, keys), 1))
+    onehot = (offset == jax.lax.broadcasted_iota(
+        jnp.int32, (n_chunk, keys), 0)).astype(jnp.float32)  # [C, ppb·P]
+    in_span = jax.lax.dot_general(
+        am_ref[0], onehot, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) > 0.5          # [gp, ppb·P]
+    visible = (q_pos >= 0) & (committed | in_span)
 
-    m_prev = m_ref[...]                                    # [gp, 128] replicated
-    l_prev = l_ref[...]
-    m_cur = jnp.max(s, axis=1)[:, None]                    # [gp, 1]
-    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-    alpha = jnp.exp(m_prev - m_new)
-    # masked entries must contribute exactly 0: for a fully masked row
-    # (padding query, q_pos = -1) m_new is still NEG_INF, so the plain
-    # exp(s - m) would be exp(0) = 1 per key and the row would silently
-    # average v. Valid rows are unchanged (exp(NEG_INF - m) underflows
-    # to 0 anyway); fully masked rows keep l = 0 and flush to 0.
-    p = jnp.where(s > NEG_INF * 0.5,
-                  jnp.exp(s - m_new[:, :1]), 0.0)          # [gp, P]
-    l_new = l_prev * alpha + jnp.broadcast_to(
-        jnp.sum(p, axis=1)[:, None], l_prev.shape)
-    acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-        p * vs_row, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+    # the block's codes, every head's, cast to f32 and laid side by side
+    # once per step (one read of each page)
+    kb = jnp.concatenate([r[0].astype(jnp.float32) for r in k_refs],
+                         axis=1)                           # [Hkv, ppb·P, hd]
+    vb = jnp.concatenate([r[0].astype(jnp.float32) for r in v_refs], axis=1)
+    # fused dequant, VMEM-only: the per-(head, position) scale is constant
+    # along hd, so it scales score columns (K) and probability columns (V)
+    # as a [1, ppb·P] row instead of rescaling the [ppb·P, hd] code block
+    ks_rows = jnp.concatenate([r[0] for r in ks_refs],
+                              axis=1) * scale              # [Hkv, ppb·P]
+    vs_rows = jnp.concatenate([r[0] for r in vs_refs], axis=1)
 
-    @pl.when(j == n_blocks - 1)
+    for h in range(hkv):
+        q = q_ref[0, h].astype(jnp.float32)                # [gp, hd]
+        s = jax.lax.dot_general(q, kb[h],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) \
+            * ks_rows[h:h + 1]
+        s = jnp.where(visible, s, NEG_INF)
+
+        m_prev = m_ref[h]                                  # [gp, 128] replicated
+        l_prev = l_ref[h]
+        m_cur = jnp.max(s, axis=1)[:, None]                # [gp, 1]
+        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+        alpha = jnp.exp(m_prev - m_new)
+        # masked entries must contribute exactly 0: for a fully masked
+        # row (padding query, q_pos = -1) m_new is still NEG_INF, so the
+        # plain exp(s - m) would be exp(0) = 1 per key and the row would
+        # silently average v. Valid rows are unchanged (exp(NEG_INF - m)
+        # underflows to 0 anyway); fully masked rows keep l = 0 and flush
+        # to 0.
+        p = jnp.where(s > NEG_INF * 0.5,
+                      jnp.exp(s - m_new[:, :1]), 0.0)      # [gp, ppb·P]
+        l_ref[h] = l_prev * alpha + jnp.broadcast_to(
+            jnp.sum(p, axis=1)[:, None], l_prev.shape)
+        acc_ref[h] = acc_ref[h] * alpha[:, :1] + jax.lax.dot_general(
+            p * vs_rows[h:h + 1], vb[h],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
-        l = l_ref[...][:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)                    # fully masked row
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for h in range(hkv):
+            l = l_ref[h][:, :1]
+            l = jnp.where(l == 0.0, 1.0, l)                # fully masked row
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
 
 def default_amask(pos: jax.Array, window: int = 0) -> jax.Array:
@@ -214,40 +254,58 @@ def paged_attention_chunk(q: jax.Array, k_pool: jax.Array, ks: jax.Array,
         am = jnp.concatenate(
             [am, jnp.zeros((b, gp - rows, c), am.dtype)], axis=1)
 
-    grid = (b, hkv, n_blocks)
-    # one head's [P, hd] code tile of the page per grid cell (the last two
-    # block dims are the array's own, as Mosaic requires); the scale block
-    # takes the page's whole [Hkv, P] strip for the same reason
-    kv_spec = pl.BlockSpec(
-        (1, 1, page_size, hd),
-        lambda bi, hi, ji, tables, pos_, rpos_, _nb=n_blocks:
-        (tables[bi * _nb + ji], hi, 0, 0))
-    scale_spec = pl.BlockSpec(
-        (1, hkv, page_size),
-        lambda bi, hi, ji, tables, pos_, rpos_, _nb=n_blocks:
-        (tables[bi * _nb + ji], 0, 0))
+    # one grid step per (row, block of ppb pages) over every KV head; the
+    # pools are passed once per page of the block, each with its own
+    # page-table index map, so the pipeline fetches the block's pages
+    # (whole [Hkv, P, hd] pages and [Hkv, P] scale strips: the last two
+    # block dims are the array's own, as Mosaic requires) while the step
+    # before computes. (The pools cannot stay in HBM behind copies started
+    # by hand: Mosaic refuses to slice a page out of an HBM ref whose last
+    # dim is under its 128-lane tile — hd 64, and the P = 16 scale
+    # strips; `tests/test_tpu_compile.py` keeps that refusal.) A last
+    # block that overhangs the bucket re-reads the row's last page,
+    # masked inside the kernel.
+    ppb = pages_per_block(n_blocks, page_size, hkv, hd, gp)
+    n_steps = pl.cdiv(n_blocks, ppb)
+
+    # (index maps are traced once per operand and step variant: lax ops
+    # keep that cheap, and only a page that can overhang is clamped)
+    def page_spec(i, shape):
+        overhangs = (n_steps - 1) * ppb + i >= n_blocks
+
+        def index(bi, ji, tables, pos_, rpos_):
+            col = jax.lax.add(jax.lax.mul(ji, ppb), i)
+            if overhangs:
+                col = jax.lax.min(col, n_blocks - 1)
+            return ((tables[jax.lax.add(jax.lax.mul(bi, n_blocks), col)],)
+                    + (0,) * (len(shape) - 1))
+        return pl.BlockSpec(shape, index)
+
+    def page_specs(i):                                   # k, ks, v, vs
+        kv = page_spec(i, (1, hkv, page_size, hd))
+        sc = page_spec(i, (1, hkv, page_size))
+        return [kv, sc, kv, sc]
+
     kernel = functools.partial(_paged_attn_kernel, page_size=page_size,
-                               n_blocks=n_blocks, n_chunk=c, n_groups=g,
-                               scale=scale, window=window)
+                               ppb=ppb, n_chunk=c,
+                               n_groups=g, scale=scale, window=window)
+    row_spec = pl.BlockSpec((1, hkv, gp, hd),
+                            lambda bi, ji, tables, pos_, rpos_:
+                            (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, gp, hd),
-                         lambda bi, hi, ji, tables, pos_, rpos_:
-                         (bi, hi, 0, 0)),
-            kv_spec, scale_spec, kv_spec, scale_spec,
-            pl.BlockSpec((1, gp, c),
-                         lambda bi, hi, ji, tables, pos_, rpos_:
-                         (bi, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, gp, hd),
-                               lambda bi, hi, ji, tables, pos_, rpos_:
-                               (bi, hi, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((gp, 128), jnp.float32),
-                        pltpu.VMEM((gp, 128), jnp.float32),
-                        pltpu.VMEM((gp, hd), jnp.float32)],
+        grid=(b, n_steps),
+        in_specs=[row_spec, *page_specs(0),
+                  pl.BlockSpec((1, gp, c),
+                               lambda bi, ji, tables, pos_, rpos_:
+                               (bi, 0, 0)),
+                  *(s for i in range(1, ppb) for s in page_specs(i))],
+        out_specs=row_spec,
+        scratch_shapes=[pltpu.VMEM((hkv, gp, 128), jnp.float32),
+                        pltpu.VMEM((hkv, gp, 128), jnp.float32),
+                        pltpu.VMEM((hkv, gp, hd), jnp.float32)],
     )
+    extra = [k_pool, ks, v_pool, vs] * (ppb - 1)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -255,11 +313,11 @@ def paged_attention_chunk(q: jax.Array, k_pool: jax.Array, ks: jax.Array,
         interpret=interpret,
         name="paged_attention_chunk",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
     )(page_table.reshape(-1).astype(jnp.int32),
       pos.reshape(-1).astype(jnp.int32),
       rpos.reshape(-1).astype(jnp.int32),
-      qr, k_pool, ks, v_pool, vs, am)
+      qr, k_pool, ks, v_pool, vs, am, *extra)
     out = out[:, :, :rows].reshape(b, hkv, c, g, hd)
     return jnp.moveaxis(out, 2, 1)
 
@@ -276,9 +334,9 @@ def paged_attention_chunk_sharded(q: jax.Array, k_pool: jax.Array,
     """Tensor-parallel form: the chunk kernel under `shard_map` over the
     KV-head axis of the ``model`` mesh axis.
 
-    KV heads are independent throughout — the online softmax, the
-    watermark/ancestor mask, and the dequant all run per (batch, kv-head)
-    grid cell — so each mesh shard simply runs the unmodified kernel body
+    KV heads are independent throughout — the online softmax and the
+    dequant run per KV head, and the watermark/ancestor mask depends on
+    the row alone — so each mesh shard simply runs the unmodified kernel
     over its local ``Hkv / |model|`` heads of the pool
     (`distributed.paged_cache_pspec` stripes the pools the same way) with
     ZERO cross-device communication inside the kernel; the output

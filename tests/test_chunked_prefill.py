@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 import repro.configs as C
-from repro.kernels.paged_attention import paged_attention_chunk
+from repro.kernels.paged_attention import (paged_attention_chunk,
+                                           pages_per_block)
 from repro.kernels.ref import paged_attention_chunk_ref
 from repro.models import build_model
 from repro.models.attention import _kv_quantize
@@ -188,18 +189,51 @@ def test_bounded_compile_family_for_all_prompt_lengths(model_and_params):
     (1, 16, 1, 1, 128, 16, 3, 8),   # MQA, page-sized chunk
     (3, 3, 2, 9, 64, 8, 5, 20),     # row dim not a sublane multiple (pad)
     (2, 1, 4, 2, 64, 16, 2, 40),    # decode form (C = 1)
+    (4, 16, 4, 7, 128, 16, 21, 90),  # 7B heads, a last block of 5 pages
+    (4, 3, 2, 7, 64, 8, 19, 80),    # 0.5B heads, verify width, last block 3
+    (3, 1, 1, 7, 128, 8, 19, 60),   # decode, one KV head, last block 3
 ])
 def test_chunk_kernel_matches_oracle(b, c, hkv, g, hd, page, nblk, npages):
-    rng = np.random.default_rng(hash((b, c, hkv, g)) % 2**31)
-    kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)) * 2,
-                     jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
-    k, ks = _kv_quantize(kf)
-    v, vs = _kv_quantize(vf)
+    """Kernel ≡ oracle over contiguous chunks at assorted watermarks: a
+    fully padded row (exact zeros), a context that ends in the first page,
+    a span that straddles a block of pages (a tree ancestor mask on odd
+    rows, plain causality on even ones), stale table entries past every
+    watermark, a padded chunk tail; with and without a sliding window."""
+    rng = np.random.default_rng(hash((b, c, hkv, g, nblk)) % 2**31)
+    k, ks, v, vs = _rand_pool(rng, npages, page, hkv, hd)
     q = jnp.asarray(rng.normal(size=(b, c, hkv, g, hd)), jnp.float32)
     table = jnp.asarray(rng.integers(1, npages, (b, nblk)), jnp.int32)
-    pos = jnp.asarray(rng.integers(0, page * nblk, (b, c)), jnp.int32)
-    if c > 1:                          # padding queries must output zero
+    span = page * nblk
+    block = page * pages_per_block(nblk, page, hkv, hd, max(8, c * g))
+    base = rng.integers(0, span - c + 1, b)
+    base[-1] = min(page // 2, span - c)  # context ends in the first page
+    if b > 2:                            # span straddles a block edge
+        edge = block if block < span else page * (nblk // 2)
+        base[1] = min(max(edge - c // 2 - 1, 0), span - c)
+    pos = base[:, None] + np.arange(c)[None, :]
+    if c > 1:                            # padding queries output zero
+        pos[:, -1] = -1
+    if b > 1:                            # a fully padded row
+        pos[0] = -1
+    amask = np.tril(np.ones((c, c), bool))[None] & (pos >= 0)[:, None, :]
+    for r in range(1, b, 2):             # random trees on odd rows
+        amask[r] &= rng.random((c, c)) < 0.6
+        amask[r][np.diag_indices(c)] = pos[r] >= 0
+    pos, amask = jnp.asarray(pos, jnp.int32), jnp.asarray(amask)
+    for window in (0, page + 3):
+        out = paged_attention_chunk(q, k, ks, v, vs, table, pos,
+                                    amask=amask, window=window,
+                                    interpret=True)
+        ref = paged_attention_chunk_ref(q, k, ks, v, vs, table, pos,
+                                        amask=amask, window=window)
+        assert float(jnp.abs(out - ref).max()) < 1e-5
+        if c > 1:
+            assert float(jnp.abs(out[:, -1]).max()) == 0.0
+        if b > 1:
+            assert float(jnp.abs(out[0]).max()) == 0.0
+    # positions drawn per query anywhere in the bucket, not as a chunk
+    pos = jnp.asarray(rng.integers(0, span, (b, c)), jnp.int32)
+    if c > 1:
         pos = pos.at[:, -1].set(-1)
     out = paged_attention_chunk(q, k, ks, v, vs, table, pos, interpret=True)
     ref = paged_attention_chunk_ref(q, k, ks, v, vs, table, pos)

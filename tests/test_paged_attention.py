@@ -17,20 +17,44 @@ from repro.serving.kv_pager import commit_prefill
     (2, 1, 1, 128, 16, 2, 6),    # MQA, single group
     (4, 2, 9, 64, 8, 3, 20),     # group dim not a sublane multiple (pad)
     (1, 4, 2, 64, 16, 5, 40),
+    (3, 4, 7, 128, 16, 21, 90),  # 7B heads, a last block of 5 pages
+    (4, 2, 7, 64, 8, 19, 60),    # 0.5B heads, a last block of 3 pages
 ])
 def test_kernel_matches_oracle(b, hkv, g, hd, page, nblk, npages):
+    """Kernel ≡ oracle on ``b`` rows at random positions in the bucket,
+    plus two rows more: a padding row (pos -1) that reads exact zeros and
+    a context that ends in the first page. Whatever the table holds past
+    a row's watermark — inside the watermark's block of pages or beyond
+    it — leaves the output bit-identical."""
     rng = np.random.default_rng(hash((b, hkv, g)) % 2**31)
     kf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)) * 2,
                      jnp.float32)
     vf = jnp.asarray(rng.normal(size=(npages, hkv, page, hd)), jnp.float32)
     k, ks = _kv_quantize(kf)
     v, vs = _kv_quantize(vf)
-    q = jnp.asarray(rng.normal(size=(b, hkv, g, hd)), jnp.float32)
-    table = jnp.asarray(rng.integers(1, npages, (b, nblk)), jnp.int32)
-    pos = jnp.asarray(rng.integers(0, page * nblk, (b,)), jnp.int32)
-    out = paged_attention(q, k, ks, v, vs, table, pos, interpret=True)
-    ref = paged_attention_ref(q, k, ks, v, vs, table, pos)
-    assert float(jnp.abs(out - ref).max()) < 1e-5
+    q = rng.normal(size=(b, hkv, g, hd))
+    table = rng.integers(1, npages, (b, nblk))
+    pos = rng.integers(0, page * nblk, (b,))
+    # the extra rows: a padding row, a context that ends in the first page
+    q = jnp.asarray(np.concatenate([q, rng.normal(size=(2, hkv, g, hd))]),
+                    jnp.float32)
+    table = np.concatenate([table, rng.integers(1, npages, (2, nblk))])
+    pos = np.concatenate([pos, [-1, page // 2]])
+    b += 2
+    out = paged_attention(q, k, ks, v, vs, jnp.asarray(table, jnp.int32),
+                          jnp.asarray(pos, jnp.int32), interpret=True)
+    ref = paged_attention_ref(q, k, ks, v, vs, jnp.asarray(table, jnp.int32),
+                              jnp.asarray(pos, jnp.int32))
+    live = pos >= 0                      # the oracle knows no padding
+    assert float(jnp.abs(out[live] - ref[live]).max()) < 1e-5
+    assert float(jnp.abs(out[~live]).max(initial=0.0)) == 0.0
+    stale = table.copy()
+    for r in range(b):                   # redraw every page past pos
+        past = np.arange(nblk) > max(pos[r], 0) // page
+        stale[r, past] = rng.integers(1, npages, past.sum())
+    out2 = paged_attention(q, k, ks, v, vs, jnp.asarray(stale, jnp.int32),
+                           jnp.asarray(pos, jnp.int32), interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 
 
 def test_kernel_masks_stale_table_entries():

@@ -1,6 +1,8 @@
 """Compile the serving path's Pallas kernels for a TPU v5e that is
 described, not attached, at Qwen2.5-0.5B widths (d 896, 14/2 heads,
-head_dim 64, d_ff 4864, GS 64).
+head_dim 64, d_ff 4864, GS 64); the paged-attention kernel also at
+Qwen2.5-7B's attention shapes (28/4 heads, head_dim 128) over a 128-page
+context bucket.
 
 Interpret mode on CPU cannot see what Mosaic refuses (block shapes off
 the (8, 128) tiling, casts the chip lacks, VMEM overruns); this compile
@@ -14,6 +16,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
@@ -87,27 +91,76 @@ def test_awq_matmul_compiles_for_v5e(one_chip, m, k, n):
     assert _custom_calls(compiled) == 1
 
 
-def _paged_args(c, sharding, head_axis=None):
+# (chunk width, KV heads, query heads, head_dim, context-bucket pages,
+# pool pages) of one attention layer's call
+PAGED_SHAPES = {
+    "decode": (1, KV_HEADS, HEADS, HEAD_DIM, PAGES_PER_SLOT, NUM_PAGES),
+    "chunk": (CHUNK, KV_HEADS, HEADS, HEAD_DIM, PAGES_PER_SLOT, NUM_PAGES),
+    "7b_chunk": (16, 4, 28, 128, 128, 32 * 128 + 1),
+}
+
+
+def _paged_args(shape, sharding, head_axis=None):
     """Shapes of one layer's int8 pool + a [SLOTS, c] query block."""
-    g = HEADS // KV_HEADS
+    c, hkv, heads, hd, n_blocks, n_pages = shape
     hp = P(None, head_axis, None)
-    return (_sds((SLOTS, c, KV_HEADS, g, HEAD_DIM), jnp.float32,
+    return (_sds((SLOTS, c, hkv, heads // hkv, hd), jnp.float32,
                  sharding(P(None, None, head_axis, None, None))),
-            _sds((NUM_PAGES, KV_HEADS, PAGE, HEAD_DIM), jnp.int8,
-                 sharding(P(*hp, None))),
-            _sds((NUM_PAGES, KV_HEADS, PAGE), jnp.float32, sharding(hp)),
-            _sds((NUM_PAGES, KV_HEADS, PAGE, HEAD_DIM), jnp.int8,
-                 sharding(P(*hp, None))),
-            _sds((NUM_PAGES, KV_HEADS, PAGE), jnp.float32, sharding(hp)),
-            _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, sharding(P(None, None))),
+            _sds((n_pages, hkv, PAGE, hd), jnp.int8, sharding(P(*hp, None))),
+            _sds((n_pages, hkv, PAGE), jnp.float32, sharding(hp)),
+            _sds((n_pages, hkv, PAGE, hd), jnp.int8, sharding(P(*hp, None))),
+            _sds((n_pages, hkv, PAGE), jnp.float32, sharding(hp)),
+            _sds((SLOTS, n_blocks), jnp.int32, sharding(P(None, None))),
             _sds((SLOTS, c), jnp.int32, sharding(P(None, None))))
 
 
-@pytest.mark.parametrize("c", [1, CHUNK], ids=["decode", "chunk"])
-def test_paged_attention_compiles_for_v5e(one_chip, c):
+@pytest.mark.parametrize("shape", PAGED_SHAPES.values(), ids=PAGED_SHAPES)
+def test_paged_attention_compiles_for_v5e(one_chip, shape):
     compiled = jax.jit(paged_attention_chunk).lower(
-        *_paged_args(c, lambda spec: one_chip)).compile()
+        *_paged_args(shape, lambda spec: one_chip)).compile()
     assert _custom_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("page_shape,dtype", [
+    ((KV_HEADS, PAGE, HEAD_DIM), jnp.int8),      # 0.5B K/V codes, hd 64
+    ((KV_HEADS, PAGE), jnp.float32),             # a page's scale strips
+], ids=["codes_hd64", "scales"])
+def test_page_copy_out_of_hbm_is_refused_for_v5e(one_chip, page_shape,
+                                                 dtype):
+    """Why `paged_attention_chunk` takes each page of a block as operands
+    of its own: a copy started by hand of one page out of a pool left in
+    HBM (``memory_space=pl.ANY``) does not compile where the page's last
+    dim is under the 128-lane tile. If this starts to compile, the pools
+    can go back to one operand each behind manual DMAs."""
+    ppb, n_blocks = 4, 8
+
+    def kernel(tables_ref, pool_hbm, o_ref, buf, sem):
+        b, j = pl.program_id(0), pl.program_id(1)
+        copies = [pltpu.make_async_copy(
+            pool_hbm.at[tables_ref[b * n_blocks + j * ppb + i]],
+            buf.at[i], sem) for i in range(ppb)]
+        for cp in copies:
+            cp.start()
+        for cp in copies:
+            cp.wait()
+        o_ref[...] = jnp.sum(buf[...].astype(jnp.float32), axis=0)[None]
+
+    zeros = (0,) * len(page_shape)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(SLOTS, n_blocks // ppb),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, *page_shape),
+                                   lambda b, j, t: (b, *zeros)),
+            scratch_shapes=[pltpu.VMEM((ppb, *page_shape), dtype),
+                            pltpu.SemaphoreType.DMA]),
+        out_shape=jax.ShapeDtypeStruct((SLOTS, *page_shape), jnp.float32))
+    lowered = jax.jit(call).lower(
+        _sds((SLOTS * n_blocks,), jnp.int32, one_chip),
+        _sds((NUM_PAGES, *page_shape), dtype, one_chip))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        lowered.compile()
 
 
 @pytest.fixture(scope="module")
@@ -116,10 +169,10 @@ def tp2_mesh(topo):
     return Mesh(np.asarray(topo.devices[:2]), ("model",))
 
 
-@pytest.mark.parametrize("c", [1, CHUNK], ids=["decode", "chunk"])
-def test_sharded_paged_attention_compiles_for_v5e(tp2_mesh, c):
+@pytest.mark.parametrize("shape", PAGED_SHAPES.values(), ids=PAGED_SHAPES)
+def test_sharded_paged_attention_compiles_for_v5e(tp2_mesh, shape):
     """`shard_map` over KV heads keeps the kernel on each chip."""
-    args = _paged_args(c, lambda spec: NamedSharding(tp2_mesh, spec),
+    args = _paged_args(shape, lambda spec: NamedSharding(tp2_mesh, spec),
                        head_axis="model")
     fn = jax.jit(lambda *a: paged_attention_chunk_sharded(*a,
                                                           mesh=tp2_mesh))

@@ -20,9 +20,9 @@ tokens they streamed.
 """
 from __future__ import annotations
 
-import importlib
-
 import numpy as np
+
+from bench import plugins
 
 SAMPLE = 4          # requests compared in every run
 
@@ -52,15 +52,11 @@ def compared(rec, seed: int) -> list:
             for s in sample(list(rec.sent.values()), seed)]
 
 
-def reference_module(cfg: dict):
-    return importlib.import_module(f"bench.reference.{cfg['reference']}")
-
-
 def gaps(w: dict, cfg: dict, seqs: list, control: bool = False) -> list:
     """Per sequence (prompt, served): the reference's gap at each served
     token, and, with ``control``, the gap of the token that the control's
     lower precision puts first at each of those positions."""
-    ref = reference_module(cfg)
+    ref = plugins.reference(cfg)
     out = []
     for prompt, served in seqs:
         toks = np.concatenate([prompt, served]).astype(np.int32)

@@ -6,7 +6,7 @@ rotary embeddings on the two halves of each head (``rotate_half``),
 causal softmax, a SwiGLU MLP, a final RMSNorm and the vocabulary
 projection (the embedding's transpose when tied). Each int4 linear is
 dequantized here, from the benchmark's own weight layout (see
-``bench/weights.py``): ``w = (code - zero) * scale``.
+``bench/archs/qwen2.py``): ``w = (code - zero) * scale``.
 
 No kernels, no cache, no batching tricks: one layer at a time over the
 whole sequence, each matmul at ``precision="highest"``. It imports

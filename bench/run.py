@@ -4,7 +4,9 @@
 
 The cell is looked up by name in ``BENCHMARK.json`` at the root of the
 checkout; its configuration, traffic mix, limit and per-layer metric
-readers are files under ``bench/`` found by name. In order, a run
+readers, and the architecture module and plain reference that its
+configuration names, are files under ``bench/`` found by name
+(``bench/plugins.py``). In order, a run
 
 1. makes the weights on the device from ``--seed``;
 2. builds the program's engine (or a Router over one-chip replicas) and
@@ -39,10 +41,11 @@ from pathlib import Path  # noqa: E402
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 TRACE_S = 10.0       # length of the traced part, at the end of the window
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
 
-
-class CellError(Exception):
-    """The cell, or a file it names, is missing or malformed."""
+from bench import plugins  # noqa: E402
+from bench.plugins import CellError  # noqa: E402
 
 
 def load_cell(name: str) -> dict:
@@ -66,6 +69,8 @@ def load_cell(name: str) -> dict:
     out = dict(cell)
     for what, path in files.items():
         out[what] = json.loads(path.read_text())
+    plugins.arch(out["config"])
+    plugins.reference(out["config"])
     e2e = [m for m in spec["end_to_end"]
            if name in m.get("workloads", [name])]
     moved = {m["name"] for m in e2e}
@@ -180,7 +185,6 @@ def run_cell(args, require_tpu: bool = True, fault=None,
     cell = cell or load_cell(args.workload)
     cfg = cell["config"]
     enable_cache()
-    sys.path.insert(0, str(ROOT))
     from bench import check, replay, weights
 
     device = device_info(cell["chips"], require_tpu)

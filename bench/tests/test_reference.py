@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from bench import check, sut, weights
+from bench.archs import qwen2 as qwen2_arch
 from bench.reference import qwen2
 
 SEED = 2 ** 31 + 11
@@ -36,7 +37,7 @@ def prompts(n_prefix=0):
 
 def test_dequant_matches_program(tiny):
     w = weights.make_weights(tiny, SEED)
-    params = sut.program_params(tiny, w)
+    params = qwen2_arch.program_params(tiny, w)
     from repro.core.packing import dequantize_packed
     p = params["segments"]["seg_0"]["mlp"]["down"]
     one = type(p)(p.qweight[1], p.scales[1], p.zeros[1], p.input_scale[1],

@@ -6,6 +6,7 @@ from pathlib import Path
 import pytest
 
 from bench import trace, work
+from bench.archs import qwen2
 
 HERE = Path(__file__).resolve().parents[1]
 TRACE = HERE / "testdata" / "qwen25-0.5b.4steps.xplane.pb"
@@ -33,14 +34,14 @@ def test_kernel_calls_and_shares(red):
     # seven int4 linears and one paged-attention call a layer, each step
     assert red.calls["awq_matmul"] == 4 * LAYERS * 7
     assert red.calls["paged_attention_chunk"] == 4 * LAYERS
-    for k in trace.KERNELS:
+    for k in red.calls:
         share = red.roofline_share(k)
         assert 0 < share <= 100
     assert set(red.bounds["awq_matmul"]) <= {"compute", "memory"}
 
 
 def test_kernel_shapes_are_the_configs(red):
-    dims = set(work.linear_dims(CFG).values())
+    dims = set(qwen2.linear_dims(CFG).values())
     assert red.linear_dims and red.linear_dims <= dims
 
 

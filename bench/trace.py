@@ -17,9 +17,9 @@ import shutil
 import time
 from pathlib import Path
 
-from bench import work
+from bench import plugins, work
 
-KERNELS = ("awq_matmul", "paged_attention_chunk")
+KERNELS = plugins.names("kernels")    # each counted by bench/kernels/<name>.py
 SPANS = ("bench.step", "bench.submit", "bench.collect",
          "bench.generator_wait")
 
@@ -149,20 +149,9 @@ def op_name(event_name: str) -> str:
 
 
 def kernel_work(kernel: str, text: str, cfg: dict):
-    """(FLOPs, bytes) of one kernel call from its instruction text."""
-    sh = shapes(text)
-    if kernel == "awq_matmul":
-        # out f32[M, N], operands x bf16[M, K], qweight s32[K/8, N], ...
-        (_, out), (_, x) = sh[0], sh[1]
-        return work.awq_matmul(x[0], x[1], out[1],
-                               cfg["quantization"]["group_size"])
-    # out f32[B, Hkv, rows, hd]; operands: tables, pos, rpos, q, k pool...
-    b, hkv, _, hd = sh[0][1]
-    n_pages = sh[1][1][0] // b
-    c = sh[2][1][0] // b
-    page = sh[5][1][2]
-    g = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
-    return work.paged_attention_chunk(b, c, hkv, g, hd, n_pages, page)
+    """(FLOPs, bytes) of one kernel call from its instruction text, by the
+    kernel's module ``bench/kernels/<kernel>.py``."""
+    return plugins.kernel(kernel).work(text, cfg)
 
 
 def reduce_file(path: Path, cfg: dict, chips: int, peak: dict) -> Reduced | None:

@@ -2,12 +2,16 @@
 each kernel call and of a model token, computed from shapes alone.
 
 Peaks come from one table keyed by ``device_kind``; a chip that is not in
-it is an error, never a default. The per-linear (K, N) list and the peak
-figures were first written in the program's roofline module
-(``src/repro/roofline``); they are kept here so that no change to the
-program can move them.
+it is an error, never a default. The peak figures were first written in
+the program's roofline module (``src/repro/roofline``); they are kept
+here so that no change to the program can move them. A kernel's module
+(``bench/kernels/<op>.py``) reads a call's shapes and counts it with the
+formulas here; a model token's FLOPs are its architecture module's
+(``bench/archs/<arch>.py``).
 """
 from __future__ import annotations
+
+from bench import plugins
 
 # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
 # 819 GB/s per chip.
@@ -59,23 +63,14 @@ def paged_attention_chunk(b: int, c: int, hkv: int, g: int, hd: int,
     return flops, nbytes
 
 
-def linear_dims(cfg: dict) -> dict[str, tuple[int, int]]:
-    """(K, N) of each linear of one Qwen2 layer."""
-    d = cfg["hidden_size"]
-    q = cfg["num_attention_heads"] * cfg["head_dim"]
-    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    f = cfg["intermediate_size"]
-    return {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-            "gate": (d, f), "up": (d, f), "down": (f, d)}
-
-
 def token_flops(cfg: dict) -> int:
-    """Model FLOPs of one token through the layers' linears (attention
-    scores and the head excluded; see `head_flops`)."""
-    per_layer = sum(2 * k * n for k, n in linear_dims(cfg).values())
-    return cfg["num_hidden_layers"] * per_layer
+    """Model FLOPs of one token through the layers' linears, as the
+    configuration's architecture module counts them: active FLOPs, so
+    only the experts a token is routed to (attention scores and the head
+    excluded; see `head_flops`)."""
+    return plugins.arch(cfg).token_flops(cfg)
 
 
 def head_flops(cfg: dict) -> int:
     """FLOPs of the vocabulary projection of one sampled row."""
-    return 2 * cfg["hidden_size"] * cfg["vocab_size"]
+    return plugins.arch(cfg).head_flops(cfg)
